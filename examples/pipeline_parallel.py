@@ -50,6 +50,7 @@ def main():
     """)
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"    # never reach for an accelerator
     out = subprocess.run([sys.executable, "-c", script], env=env,
                          capture_output=True, text=True, timeout=600,
                          cwd=os.path.dirname(os.path.dirname(

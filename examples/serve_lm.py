@@ -24,8 +24,8 @@ def main():
     for k, names in sorted(ks.items()):
         print(f"  k={k}: {len(names)} GEMM kinds e.g. {names[:3]}")
 
-    reqs = serve.main(["--arch", "qwen2-0.5b", "--requests", "6",
-                       "--max-new", "16"])
+    reqs = serve.main(["--arch", "qwen2-0.5b", "--reduced", "--requests",
+                       "6", "--max-new", "16"])
     assert all(len(r.out_tokens) == 16 for r in reqs)
     print("example complete")
 

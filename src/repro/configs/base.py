@@ -108,9 +108,9 @@ class ModelConfig:
     # unknown name fails with the registered list, not deep in dispatch.
     gemm_backend: str = "xla"
     # Pallas interpret-mode override threaded to every kernel launch.
-    # None resolves via the REPRO_PALLAS_INTERPRET env var, else the
-    # default (compiled on real TPU backends, interpreted elsewhere) —
-    # see kernels.runtime.resolve_interpret.  True/False force it.
+    # None: compiled on TPU backends, interpreted elsewhere; True/False
+    # force it, except that interpret mode on a TPU raises — see
+    # kernels.runtime.resolve_interpret.
     pallas_interpret: Optional[bool] = None
     # --- SPMD sharded dispatch -------------------------------------------
     # (data, model) host-mesh axis sizes for sharded GEMM dispatch; ()

@@ -171,6 +171,33 @@ def store_phase(y, y2=None, w_scale=None, w2_scale=None, bias=None,
     return out
 
 
+# Lane width of a TPU vector register: Mosaic accepts a block (and an
+# in-kernel slice) whose last dim is a multiple of it, or the whole axis.
+LANE = 128
+
+
+def k_tiling(K: int, bk: int, k_collapse: int):
+    """Lane-aligned K schedule -> ``(k, n_steps, kk, K_pad)``.
+
+    Each of the ``k`` sub-tiles of a collapsed block is a multiple of
+    :data:`LANE` wide (``bk`` rounds up to one), so the x block
+    ``(bm, kk)`` and every in-kernel slice ``x[:, i*bk:(i+1)*bk]`` sit on
+    lane boundaries.  ``K`` pads with zeros to ``K_pad = n_steps * kk``
+    (exact: zero columns add 0 to the accumulator); e.g. K=896 pads to
+    1024 at k=2 and k=4.  Two shapes need no padding: a K that fits one
+    uncollapsed sub-tile is its own full-width block, and a collapse
+    deeper than K's lane-tile count is clamped, since the extra sub-tiles
+    would hold nothing but padding."""
+    bk = -(-bk // LANE) * LANE
+    k = max(1, min(k_collapse, -(-K // LANE)))
+    n_steps = -(-K // (bk * k))
+    if n_steps == 1 and k == 1:
+        return 1, 1, K, K
+    bk_eff = -(-K // (n_steps * k * LANE)) * LANE
+    kk = bk_eff * k
+    return k, n_steps, kk, n_steps * kk
+
+
 # ---------------------------------------------------------------------------
 # single-GEMM kernel (optionally dual-contraction) with fused epilogue
 
@@ -338,13 +365,13 @@ def arrayflex_gemm(x, w, *, w2=None, bias=None, bias2=None,
       * empty M, N or K short-circuits: the epilogue is applied to the
         exact zero accumulator(s) (NOT necessarily a zero result — a bias
         epilogue with K=0 returns ``act(bias)``);
-      * K may be anything.  The K axis is tiled into
-        ``n_steps = ceil(K / (bk * k_collapse))`` collapsed blocks of
-        ``k_collapse`` equal sub-tiles each; when K does not fill that grid
-        exactly, X and W are zero-padded along K (zeros contribute exactly
-        0 to the fp32 accumulator, so the result is exact — previously the
-        kernel silently *dropped* trailing K columns whenever the clamped
-        block was not divisible by k_collapse, e.g. K=130, k_collapse=4).
+      * K may be anything.  The K axis is tiled by :func:`k_tiling` into
+        ``n_steps`` collapsed blocks of ``k_collapse`` equal lane-aligned
+        sub-tiles each; when K does not fill that grid exactly, X and W
+        are zero-padded along K (zeros contribute exactly 0 to the fp32
+        accumulator, so the result is exact — previously the kernel
+        silently *dropped* trailing K columns whenever the clamped block
+        was not divisible by k_collapse, e.g. K=130, k_collapse=4).
     """
     M, K = x.shape
     K2, N = w.shape
@@ -392,12 +419,7 @@ def arrayflex_gemm(x, w, *, w2=None, bias=None, bias2=None,
         raise ValueError(
             f"bm must divide M and bn must divide N: "
             f"M={M}, bm={bm}, N={N}, bn={bn}")
-    # exact K tiling: choose the sub-tile width so the collapsed block grid
-    # covers K with minimal zero padding (never drop columns).
-    n_steps = -(-K // (bk * k_collapse))           # ceil
-    bk_eff = -(-K // (n_steps * k_collapse))       # ceil
-    kk = bk_eff * k_collapse
-    K_pad = n_steps * kk
+    k_collapse, n_steps, kk, K_pad = k_tiling(K, bk, k_collapse)
     if K_pad != K:
         x = jnp.pad(x, ((0, 0), (0, K_pad - K)))
         w = jnp.pad(w, ((0, K_pad - K), (0, 0)))
@@ -535,10 +557,7 @@ def arrayflex_expert_gemm(x, w, *, w_scale=None, act_quant: bool = False,
         raise ValueError(
             f"bm must divide T and bn must divide N: "
             f"T={T}, bm={bm}, N={N}, bn={bn}")
-    n_steps = -(-K // (bk * k_collapse))
-    bk_eff = -(-K // (n_steps * k_collapse))
-    kk = bk_eff * k_collapse
-    K_pad = n_steps * kk
+    k_collapse, n_steps, kk, K_pad = k_tiling(K, bk, k_collapse)
     if K_pad != K:
         x = jnp.pad(x, ((0, 0), (0, 0), (0, K_pad - K)))
         w = jnp.pad(w, ((0, 0), (0, K_pad - K), (0, 0)))
@@ -552,9 +571,10 @@ def arrayflex_expert_gemm(x, w, *, w_scale=None, act_quant: bool = False,
         pl.BlockSpec((1, bm, kk), lambda e, i, j, s: (e, i, s)),
         pl.BlockSpec((1, kk, bn), lambda e, i, j, s: (e, s, j)),
     ]
-    if quant:
-        operands.append(w_scale)
-        in_specs.append(pl.BlockSpec((1, bn), lambda e, i, j, s: (e, j)))
+    if quant:                           # (E, 1, N): the block's last two
+        operands.append(w_scale.reshape(E, 1, N))   # dims are (1, bn)
+        in_specs.append(pl.BlockSpec((1, 1, bn),
+                                     lambda e, i, j, s: (e, 0, j)))
     return pl.pallas_call(
         kernel,
         grid=grid,

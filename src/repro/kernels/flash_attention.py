@@ -77,7 +77,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     chunk pick runs as-is instead of degenerating via a divisor search).
 
     ``interpret=None`` resolves via :func:`repro.kernels.runtime
-    .resolve_interpret` (env override, compiled on TPU).
+    .resolve_interpret` (compiled on TPU, interpreted elsewhere).
     """
     interpret = resolve_interpret(interpret)
     BH, S, D = q.shape

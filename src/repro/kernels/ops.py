@@ -12,10 +12,11 @@ kernel's KV-chunk with the same machinery.
 and model tracing + per-request serving hit it with the same handful of
 shapes thousands of times.
 
-Pallas ``interpret`` resolution (the TPU-hardware switch): an explicit
-argument wins, else the ``REPRO_PALLAS_INTERPRET`` env var, else interpret
-mode everywhere but on real TPU backends.  ``ModelConfig.pallas_interpret``
-threads the explicit argument from model configs down to every kernel.
+Pallas ``interpret`` resolution (``kernels.runtime.resolve_interpret``):
+compiled on TPU backends, interpreted elsewhere; an explicit argument wins
+off the TPU, and interpret mode on a TPU raises.
+``ModelConfig.pallas_interpret`` threads the explicit argument from model
+configs down to every kernel.
 """
 from __future__ import annotations
 

@@ -1,23 +1,23 @@
 """Kernel execution-policy helpers shared by every Pallas entry point."""
 from __future__ import annotations
 
-import os
-
 import jax
 
 
 def resolve_interpret(value=None) -> bool:
-    """Pallas interpret-mode resolution chain.
+    """Pallas interpret-mode resolution.
 
-    Explicit argument (e.g. threaded from ``ModelConfig.pallas_interpret``)
-    > ``REPRO_PALLAS_INTERPRET`` env var ("0"/"false"/"no" disable, anything
-    else enables) > default: compiled on real TPU backends, interpreted
-    everywhere else.  Before this chain existed every kernel hard-coded
-    ``interpret=True``, so TPU hardware runs executed the Mosaic emulator.
+    ``None`` (the default, and ``ModelConfig.pallas_interpret``'s) runs
+    compiled on a TPU backend and in the interpreter everywhere else.  An
+    explicit ``True``/``False`` wins, except that interpret mode on a TPU
+    backend raises: the Mosaic emulator there would serve every kernel
+    from the host while reporting the chip as the device.
     """
-    if value is not None:
-        return bool(value)
-    env = os.environ.get("REPRO_PALLAS_INTERPRET", "")
-    if env:
-        return env.lower() not in ("0", "false", "no")
-    return jax.default_backend() != "tpu"
+    on_tpu = jax.default_backend() == "tpu"
+    if value is None:
+        return not on_tpu
+    if value and on_tpu:
+        raise ValueError(
+            "Pallas interpret mode was requested on a TPU backend; the "
+            "kernels run compiled there (leave pallas_interpret unset)")
+    return bool(value)

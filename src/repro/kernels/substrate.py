@@ -64,8 +64,8 @@ same function and equivalence tests stay meaningful.  The epilogue's
 vector ops are priced into Eq.(5')/(6') and can shift the planned k.
 
 ``ModelConfig.gemm_backend`` selects the backend model-wide and
-``ModelConfig.pallas_interpret`` (or ``REPRO_PALLAS_INTERPRET``) the
-Pallas interpret mode; callers thread both through (see models/lm.py).
+``ModelConfig.pallas_interpret`` the Pallas interpret mode; callers
+thread both through (see models/lm.py).
 New backends (quantized, ...) register with :func:`register_backend`.
 
 **Sharded SPMD dispatch**: every entry point accepts a :class:`ShardCtx`
@@ -102,7 +102,6 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.core import planner, timing
@@ -1030,8 +1029,8 @@ def _sharded_gemm(fn, x2, w, plan: GemmPlan, ctx: ShardCtx, call: GemmCall):
             out = rs.astype(jnp.float32) + out
         return out.astype(call.out_dtype or xs.dtype)
 
-    return shard_map(body, mesh=ctx.mesh, in_specs=tuple(in_specs),
-                     out_specs=ctx.out_spec, check_rep=False)(*operands)
+    return jax.shard_map(body, mesh=ctx.mesh, in_specs=tuple(in_specs),
+                         out_specs=ctx.out_spec, check_vma=False)(*operands)
 
 
 def gemm(x, w, *, site: str = "", backend: str = "xla", out_dtype=None,
@@ -1205,9 +1204,9 @@ def batched_gemm(x, w, *, site: str = "", backend: str = "xla",
         def body(xs, ws):
             return _batched_exec(xs, ws, plan, backend, out_dtype, interpret)
 
-        return shard_map(body, mesh=shard.mesh,
-                         in_specs=(shard.x_spec, shard.w_spec),
-                         out_specs=shard.out_spec, check_rep=False)(x, w)
+        return jax.shard_map(body, mesh=shard.mesh,
+                             in_specs=(shard.x_spec, shard.w_spec),
+                             out_specs=shard.out_spec, check_vma=False)(x, w)
     if _is_builtin(backend):
         _record(site, plan)
         return _batched_exec(x, w, plan, backend, out_dtype, interpret)
@@ -1293,18 +1292,18 @@ def expert_gemm(x, w, *, site: str = "", backend: str = "xla",
                 return _expert_exec(xs, ws, plan, backend, interpret, ss,
                                     actq)
 
-            return shard_map(
+            return jax.shard_map(
                 body_q, mesh=shard.mesh,
                 in_specs=(shard.x_spec, shard.w_spec,
                           P(shard.w_spec[0], None)),
-                out_specs=shard.out_spec, check_rep=False)(x, w, w_scale)
+                out_specs=shard.out_spec, check_vma=False)(x, w, w_scale)
 
         def body(xs, ws):
             return _expert_exec(xs, ws, plan, backend, interpret)
 
-        return shard_map(body, mesh=shard.mesh,
-                         in_specs=(shard.x_spec, shard.w_spec),
-                         out_specs=shard.out_spec, check_rep=False)(x, w)
+        return jax.shard_map(body, mesh=shard.mesh,
+                             in_specs=(shard.x_spec, shard.w_spec),
+                             out_specs=shard.out_spec, check_vma=False)(x, w)
     if _is_builtin(backend):
         _record(site, plan)
         return _expert_exec(x, w, plan, backend, interpret, w_scale, actq)

@@ -1,6 +1,8 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import: jax locks device count on first init.
+# A CPU-only tool: it never claims an accelerator.
 
 import argparse
 import json

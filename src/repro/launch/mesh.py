@@ -11,17 +11,11 @@ import numpy as np
 from jax.sharding import Mesh
 
 
-def _axis_types_kwargs(n_axes: int) -> dict:
-    """``axis_types`` only where the jax version has it (>= 0.5 explicit
-    sharding); older versions take no such argument."""
-    at = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (at.Auto,) * n_axes} if at is not None else {}
-
-
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_types_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(data: int = 1, model: int = 1, *, strict: bool = False):

@@ -1,7 +1,11 @@
 """Serving driver: chunked batched prefill + continuous-batching decode.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b --reduced \
+  PYTHONPATH=src python -m repro.launch.serve --arch qwen2-0.5b \
       --requests 6 --max-new 24 --prefill-chunk 32
+
+serves the published config (qwen2-0.5b: 24 layers, d_model 896) with
+random weights from the seed; ``--reduced`` swaps in the tiny CPU-test
+config.
 
 Sharded SPMD serving: ``--tp``/``--fsdp`` declare the (data, model) host
 mesh — every model GEMM then plans on its post-partition shape and runs
@@ -9,7 +13,8 @@ per-shard under jax.shard_map (see docs/substrate.md).  On CPU,
 ``--host-devices N`` fans the host out to N devices (the XLA_FLAGS
 device-count override) so a TP=4 mesh is testable on a laptop:
 
-  PYTHONPATH=src python -m repro.launch.serve --tp 4 --host-devices 8
+  PYTHONPATH=src python -m repro.launch.serve --reduced --tp 4 \
+      --host-devices 8
 
 Prints per-request outputs plus per-phase timing: prefill and decode
 throughput (tokens/s), dispatch counts, and mean time-to-first-token.
@@ -25,6 +30,7 @@ import jax
 
 from repro.configs import get_config, reduced
 from repro.kernels import substrate
+from repro.launch.compile_cache import setup_compile_cache
 from repro.models import lm
 from repro.runtime import chaos
 from repro.serving import (AdmissionError, DisaggServeConfig,
@@ -97,7 +103,9 @@ def phase_report(engine: ServingEngine, reqs) -> str:
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="serve the tiny same-family config (d_model 64, "
+                         "2 layers) instead of the published widths")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--max-new", type=int, default=24)
     ap.add_argument("--max-batch", type=int, default=4)
@@ -190,6 +198,7 @@ def main(argv=None):
 
     if args.strict_audit:
         os.environ["REPRO_STRICT_AUDIT"] = "1"
+    setup_compile_cache()
 
     cfg = get_config(args.arch)
     if args.reduced:

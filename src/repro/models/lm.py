@@ -793,7 +793,6 @@ def _pp_step(cfg: ModelConfig, params, cache, tokens, pos, lengths):
     active role's transfer pricing (sharding.use_pp_pricing), which is how
     prefill pods and decode pods legitimately hold different ``best_k``.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.parallel import pipeline as pipe
@@ -838,9 +837,9 @@ def _pp_step(cfg: ModelConfig, params, cache, tokens, pos, lengths):
             logits * (stage == n_stages - 1).astype(logits.dtype), "pod")
         return logits[:, 0], new_cache
 
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(P("pod"), P("pod"), P(), P(), P(), P()),
-                   out_specs=(P(), P("pod")), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(P("pod"), P("pod"), P(), P(), P(), P()),
+                       out_specs=(P(), P("pod")), check_vma=False)
     return fn(params["blocks"], cache, other, tokens, pos, lengths)
 
 

@@ -69,15 +69,13 @@ def make_pipelined(fn, mesh, *, axis_name: str = "pod",
 
     `stage_param_spec` is a prefix spec applied to every stage-param leaf.
     """
-    from jax.experimental.shard_map import shard_map
-
     def inner(stage_params, x_micro):
         sp = jax.tree.map(lambda a: a[0], stage_params)  # this shard's stage
         return gpipe(fn, sp, x_micro, axis_name=axis_name)
 
-    return shard_map(inner, mesh=mesh,
-                     in_specs=(stage_param_spec, x_spec),
-                     out_specs=x_spec, check_rep=False)
+    return jax.shard_map(inner, mesh=mesh,
+                         in_specs=(stage_param_spec, x_spec),
+                         out_specs=x_spec, check_vma=False)
 
 
 def staged_step(fn, x0, state, *, axis_name: str = "pod"):

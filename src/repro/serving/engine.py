@@ -349,6 +349,15 @@ class ServingEngine:
             self.slots = [Slot(i) for i in range(B)]
             self.active = []
             self._rr = 0
+        if self.mesh is not None:
+            # every device of the serving mesh holds the weights and the
+            # K/V state, so each per-GEMM shard_map slices its operand
+            # shards locally instead of fetching them from one device on
+            # every step
+            on_mesh = jax.sharding.NamedSharding(
+                self.mesh, jax.sharding.PartitionSpec())
+            self.params = jax.device_put(self.params, on_mesh)
+            self.cache = jax.device_put(self.cache, on_mesh)
 
         # --- resilience state (PR 8) ------------------------------------
         self._chaos = (chaos_mod.ChaosEngine(serve_cfg.chaos)

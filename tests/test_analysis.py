@@ -19,7 +19,6 @@ import jax.numpy as jnp
 import pytest
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.analysis import ast_lint, jaxpr_audit, kernel_check
@@ -160,8 +159,8 @@ def test_seeded_af001_bypass_gemm():
 
 def test_seeded_af002_bf16_psum_on_quantized_path():
     mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
-    f = shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
-                  in_specs=P(), out_specs=P())
+    f = jax.shard_map(lambda x: jax.lax.psum(x, "model"), mesh=mesh,
+                      in_specs=P(), out_specs=P(), check_vma=False)
     closed = jax.make_jaxpr(f)(jnp.ones((4, 4), jnp.bfloat16))
     found = jaxpr_audit.audit_closed_jaxpr(closed, quantized=True)
     assert codes(found) == ["AF002"]

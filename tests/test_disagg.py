@@ -377,8 +377,9 @@ def test_serve_cli_disagg():
     env["PYTHONPATH"] = os.path.join(REPO, "src") + os.pathsep + \
         env.get("PYTHONPATH", "")
     out = subprocess.run(
-        [sys.executable, "-m", "repro.launch.serve", "--requests", "3",
-         "--max-new", "4", "--prefill-pods", "1", "--decode-pods", "1"],
+        [sys.executable, "-m", "repro.launch.serve", "--reduced",
+         "--requests", "3", "--max-new", "4", "--prefill-pods", "1",
+         "--decode-pods", "1"],
         capture_output=True, text=True, timeout=600, env=env, cwd=REPO)
     assert out.returncode == 0, out.stdout[-3000:] + out.stderr[-2000:]
     assert "disagg: 1 prefill + 1 decode pod(s)" in out.stdout

@@ -306,22 +306,15 @@ def test_clear_plan_cache_clears_every_memo():
 
 # --------------------------------------------------------- interpret plumbing
 def test_resolve_interpret_chain(monkeypatch):
-    monkeypatch.delenv("REPRO_PALLAS_INTERPRET", raising=False)
-    # explicit argument wins
+    assert resolve_interpret(None) is True         # CPU: interpreted
     assert resolve_interpret(True) is True
     assert resolve_interpret(False) is False
-    # default: interpret everywhere but on real TPU backends
-    assert resolve_interpret(None) is (jax.default_backend() != "tpu")
-    # env var overrides the default
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
+    # on a TPU backend the default compiles, and interpret mode is refused
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     assert resolve_interpret(None) is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "false")
-    assert resolve_interpret(None) is False
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "1")
-    assert resolve_interpret(None) is True
-    # ...but never the explicit argument
-    monkeypatch.setenv("REPRO_PALLAS_INTERPRET", "0")
-    assert resolve_interpret(True) is True
+    assert resolve_interpret(False) is False
+    with pytest.raises(ValueError, match="interpret mode"):
+        resolve_interpret(True)
 
 
 def test_config_interpret_reaches_kernels(monkeypatch):

@@ -28,9 +28,10 @@ Tolerance contract (documented here and in docs/substrate.md):
   activation scales differ from the unsharded tiling.  The discrepancy
   is quantization-noise sized and bounded by the same family tolerances
   (observed ~0.022 dense / ~0.026 Mamba / ~1.14 MoE).
-* greedy streams — bit-identical run-to-run per backend, and on the
-  pinned prompts identical to the fp32 arrayflex stream (top-1 margins
-  exceed the quantization perturbation; deterministic on CPU).
+* greedy streams — bit-identical run-to-run per backend.  Against the
+  fp32 stream the comparison is on logits (teacher-forced along the
+  w8a8 stream, dense ``atol``): a random-weight top-1 margin can be
+  smaller than the quantization perturbation, so tokens may differ.
 """
 import dataclasses
 import os
@@ -374,8 +375,10 @@ def test_w8a8_forward_and_decode_match_fp32(arch):
 
 def test_w8a8_greedy_streams_bit_identical():
     """Acceptance: greedy streams are bit-identical run-to-run under
-    w8a8, and on the pinned prompts identical to the fp32 arrayflex
-    stream (the perturbation never flips a top-1 margin here)."""
+    w8a8.  Against fp32 the comparison is on logits, not tokens: with
+    random weights a top-1 margin can be smaller than the quantization
+    perturbation, so a token may flip while every logit along the w8a8
+    stream stays within the documented W8A8 tolerance of fp32."""
     prompts = [[5, 6, 7], [11, 12, 13, 14], [21, 22]]
 
     def run(backend):
@@ -391,7 +394,18 @@ def test_w8a8_greedy_streams_bit_identical():
 
     first = run("arrayflex_w8a8")
     assert first == run("arrayflex_w8a8")        # run-to-run determinism
-    assert first == run("arrayflex")
+    assert all(len(t) == 4 for t in first)
+    params = _params("qwen2-0.5b")
+    for prompt, out in zip(prompts, first):
+        # teacher-forced over the w8a8 stream: every logit that chose (or
+        # would have chosen) a token sits within ATOL of fp32
+        toks = jnp.asarray([prompt + out], jnp.int32)
+        want, _, _ = lm.forward(_cfg("qwen2-0.5b", "arrayflex"), params,
+                                {"tokens": toks})
+        got, _, _ = lm.forward(_cfg("qwen2-0.5b", "arrayflex_w8a8"),
+                               params, {"tokens": toks})
+        np.testing.assert_allclose(np.float32(got), np.float32(want),
+                                   atol=ATOL["qwen2-0.5b"])
 
 
 def test_w8a8_one_launch_per_site():
