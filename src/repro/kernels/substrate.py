@@ -1033,6 +1033,21 @@ def _sharded_gemm(fn, x2, w, plan: GemmPlan, ctx: ShardCtx, call: GemmCall):
                          out_specs=ctx.out_spec, check_vma=False)(*operands)
 
 
+def _site_scope(dispatch):
+    """Trace ``dispatch`` under ``jax.named_scope(site)``: the HLO
+    ``op_name`` metadata of each kernel it launches then carries the
+    planner site (``.../attn.wq/jit(_gemm)/pallas_call``) for a profiler's
+    op view.  Instruction names (``_gemm.N``) are unchanged."""
+    @functools.wraps(dispatch)
+    def scoped(*args, site: str = "", **kw):
+        if not site:
+            return dispatch(*args, site=site, **kw)
+        with jax.named_scope(site):
+            return dispatch(*args, site=site, **kw)
+    return scoped
+
+
+@_site_scope
 def gemm(x, w, *, site: str = "", backend: str = "xla", out_dtype=None,
          epilogue: str = "none", w2=None, bias=None, bias2=None,
          residual=None, norm_scale=None, interpret=None,
@@ -1157,6 +1172,7 @@ def _batched_exec(x, w, plan: GemmPlan, backend: str, out_dtype, interpret):
         x, w, preferred_element_type=jnp.float32).astype(out_dtype)
 
 
+@_site_scope
 def batched_gemm(x, w, *, site: str = "", backend: str = "xla",
                  out_dtype=None, interpret=None,
                  shard: Optional[ShardCtx] = None):
@@ -1241,6 +1257,7 @@ def _expert_exec(x, w, plan: GemmPlan, backend: str, interpret,
     return out.reshape(E, G, C, N_out).transpose(1, 0, 2, 3)
 
 
+@_site_scope
 def expert_gemm(x, w, *, site: str = "", backend: str = "xla",
                 interpret=None, shard: Optional[ShardCtx] = None):
     """Batched expert GEMM: x (G, E, C, K) @ w (E, K, N) -> (G, E, C, N).

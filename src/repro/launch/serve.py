@@ -45,6 +45,7 @@ def phase_report(engine: ServingEngine, reqs) -> str:
     de_tps = st["decode_tokens"] / max(st["decode_time_s"], 1e-9)
     ttfts = [r.ttft_s for r in reqs if r.ttft_s is not None]
     ttft_ms = 1e3 * sum(ttfts) / max(len(ttfts), 1)
+    wait_ms = 1e3 * st["queue_wait_s"] / max(st["admissions"], 1)
     out = (f"prefill[{engine.prefill_mode}]: {st['prefill_tokens']} tok "
            f"in {st['prefill_time_s']:.3f}s ({pf_tps:.1f} tok/s, "
            f"{st['prefill_dispatches']} dispatches, "
@@ -52,7 +53,8 @@ def phase_report(engine: ServingEngine, reqs) -> str:
            f"decode: {st['decode_tokens']} tok in "
            f"{st['decode_time_s']:.3f}s ({de_tps:.1f} tok/s, "
            f"{st['decode_dispatches']} dispatches)\n"
-           f"mean TTFT: {ttft_ms:.1f} ms")
+           f"mean TTFT: {ttft_ms:.1f} ms (mean queue wait "
+           f"{wait_ms:.1f} ms)")
     if engine.paged:
         out += (f"\npaged: peak {st['pages_used_peak']} pages, "
                 f"peak concurrency {st['concurrency_peak']}, "

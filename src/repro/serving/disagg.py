@@ -73,7 +73,7 @@ from __future__ import annotations
 import dataclasses
 import time
 from dataclasses import dataclass
-from typing import Dict, List
+from typing import Dict
 
 import jax
 import jax.numpy as jnp
@@ -86,8 +86,8 @@ from repro.kernels import substrate
 from repro.models import lm
 from repro.parallel import sharding
 from repro.serving.engine import (PREFILL_CHUNK_CHOICES, Request, ServeConfig,
-                                  ServingEngine, Slot)
-from repro.serving.errors import KernelFault, Outcome, TransferFault
+                                  ServingEngine, Slot, jit_step)
+from repro.serving.errors import Outcome, TransferFault
 from repro.serving.paged import PagePool, PagedSeq
 
 # Prefill-role chunk objective: with no decode stream to protect, the
@@ -177,34 +177,24 @@ class DisaggServingEngine(ServingEngine):
             lm._check_pp(self.dcfg)
             sharding.mesh_from_config(self.pcfg)
             sharding.mesh_from_config(self.dcfg)
-            self._decode = jax.jit(
-                lambda p, c, t, pos: lm.decode_step_pp(
-                    self.dcfg, p, c, t, pos))
-            self._prefill = jax.jit(
-                lambda p, c, t, pos, lens: lm.prefill_step_pp(
-                    self.pcfg, p, c, t, pos, lens))
+            self._decode = jit_step(lm.decode_step_pp, self.dcfg)
+            self._prefill = jit_step(lm.prefill_step_pp, self.pcfg)
         else:
             self.pcfg = dataclasses.replace(cfg, pp_role="prefill")
             self.dcfg = dataclasses.replace(cfg, pp_role="decode")
-            self._decode = jax.jit(
-                lambda p, c, t, pos: lm.decode_step(self.dcfg, p, c, t, pos))
+            self._decode = jit_step(lm.decode_step, self.dcfg)
             if self.prefill_mode == "batched":
-                self._prefill = jax.jit(
-                    lambda p, c, t, pos, lens: lm.prefill_step(
-                        self.pcfg, p, c, t, pos, lens))
+                self._prefill = jit_step(lm.prefill_step, self.pcfg)
             else:
                 # token-mode prefill runs the decode-path step, but on the
                 # PREFILL pods (prefill cache, prefill-role plans)
-                self._decode_p = jax.jit(
-                    lambda p, c, t, pos: lm.decode_step(
-                        self.pcfg, p, c, t, pos))
+                self._token_prefill_step = jit_step(lm.decode_step,
+                                                    self.pcfg)
             if self.paged:
-                self._decode_paged = jax.jit(
-                    lambda p, c, t, pos, bt: lm.decode_step_paged(
-                        self.dcfg, p, c, t, pos, bt))
-                self._prefill_paged = jax.jit(
-                    lambda p, c, t, pos, lens, bt: lm.prefill_step_paged(
-                        self.pcfg, p, c, t, pos, lens, bt))
+                self._decode_paged = jit_step(lm.decode_step_paged,
+                                              self.dcfg)
+                self._prefill_paged = jit_step(lm.prefill_step_paged,
+                                               self.pcfg)
 
         # Prefill-role chunk re-pick (see PREFILL_STEP_OVERHEAD).  An
         # explicit serve_cfg.prefill_chunk still wins, and a W8A8 backend
@@ -336,118 +326,19 @@ class DisaggServingEngine(ServingEngine):
         return True
 
     # ------------------------------------------------------------ prefill
-    def _prefill_tick(self):
+    # The base engine's prefill tick, pointed at the prefill pods' cache;
+    # a prefilled sequence is handed off instead of published.
+    def _prefill_cache(self):
+        return self.pcache
+
+    def _set_prefill_cache(self, cache):
+        self.pcache = cache
+
+    def _prefilled(self, s):
         if self.paged:
-            self._prefill_tick_paged()
-            return
-        pre = [s for s in self.slots if s.state == Slot.PREFILL]
-        if not pre:
-            return
-        if self.prefill_mode == "token":
-            for slot in pre:
-                self._prefill_token_by_token(slot)
-            return
-        B, C = self.sc.max_batch, self.prefill_chunk
-        toks = np.zeros((B, C), np.int32)
-        pos = self._pos_vector()
-        lens = np.zeros(B, np.int32)
-        for s in pre:
-            c = min(C, s.prefill_len - s.prefill_done)
-            toks[s.index, :c] = s.tokens[s.prefill_done:
-                                         s.prefill_done + c]
-            lens[s.index] = c
-        t0 = self.clock()
-        d0 = sum(substrate.DISPATCH_COUNTS.values())
-        try:
-            _, self.pcache, _ = self._guarded_dispatch(
-                lambda: (None, self._prefill(
-                    self.params, self.pcache, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(lens))[1]),
-                rows=())
-        except KernelFault as exc:
-            for s in pre:
-                self._finish(s.req, Outcome.FAILED,
-                             f"KernelFault during prefill: {exc}")
-                s.release()
-            return
-        jax.block_until_ready(self.pcache)
-        self.stats["prefill_time_s"] += self.clock() - t0
-        self.stats["prefill_dispatches"] += 1
-        self.stats["prefill_tokens"] += int(lens.sum())
-        self._count_prefill_launches(d0)
-        for s in pre:
-            s.finish_chunk(int(lens[s.index]))
-            if s.state == Slot.DECODE:
-                self._handoff_dense(s)
-
-    def _prefill_tick_paged(self):
-        pre = [s for s in self.active if s.state == PagedSeq.PREFILL]
-        if not pre:
-            return
-        sel = pre[:self.sc.max_batch]
-        B, C = self.sc.max_batch, self.prefill_chunk
-        toks = np.zeros((B, C), np.int32)
-        pos = np.zeros(B, np.int32)
-        lens = np.zeros(B, np.int32)
-        bt = np.zeros((B, self.pages_per_seq), np.int32)
-        for r, s in enumerate(sel):
-            c = min(C, s.prefill_len - s.prefill_done)
-            toks[r, :c] = s.prompt[s.prefill_done:s.prefill_done + c]
-            pos[r] = s.prefill_done
-            lens[r] = c
-            bt[r] = s.block_table
-        t0 = self.clock()
-        d0 = sum(substrate.DISPATCH_COUNTS.values())
-        try:
-            _, self.pcache, _ = self._guarded_dispatch(
-                lambda: (None, self._prefill_paged(
-                    self.params, self.pcache, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(lens),
-                    jnp.asarray(bt))[1]),
-                rows=())
-        except KernelFault as exc:
-            for s in sel:
-                self._finish(s.req, Outcome.FAILED,
-                             f"KernelFault during prefill: {exc}")
-                self._release_paged(s)
-            return
-        jax.block_until_ready(self.pcache)
-        self.stats["prefill_time_s"] += self.clock() - t0
-        self.stats["prefill_dispatches"] += 1
-        self.stats["prefill_tokens"] += int(lens.sum())
-        self._count_prefill_launches(d0)
-        for r, s in enumerate(sel):
-            s.prefill_done += int(lens[r])
-            if s.prefill_done >= s.prefill_len:
-                s.to_decode()
-                self._handoff_paged(s)
-
-    def _prefill_token_by_token(self, slot: Slot):
-        req = slot.req
-        for i, t in enumerate(slot.tokens[:-1]):
-            toks = np.zeros(self.sc.max_batch, np.int32)
-            toks[slot.index] = t
-            pos_v = self._pos_vector()
-            pos_v[slot.index] = i
-            t0 = self.clock()
-            try:
-                _, self.pcache, _ = self._guarded_dispatch(
-                    lambda tk=toks, pv=pos_v: (None, self._decode_p(
-                        self.params, self.pcache, jnp.asarray(tk),
-                        jnp.asarray(pv))[1]),
-                    rows=())
-            except KernelFault as exc:
-                self._finish(req, Outcome.FAILED,
-                             f"KernelFault during prefill: {exc}")
-                slot.release()
-                return
-            jax.block_until_ready(self.pcache)
-            self.stats["prefill_time_s"] += self.clock() - t0
-            self.stats["prefill_dispatches"] += 1
-            self.stats["prefill_tokens"] += 1
-            slot.prefill_done = i + 1
-        slot._to_decode()
-        self._handoff_dense(slot)
+            self._handoff_paged(s)
+        else:
+            self._handoff_dense(s)
 
     # ----------------------------------------------- virtual role clocks
     def _residents(self):
@@ -502,7 +393,7 @@ class DisaggServingEngine(ServingEngine):
             self.stats["preemptions"] += 1
             req.resume_prompt = list(req.prompt) + list(req.out_tokens)
             slot.release()
-            self.queue.insert(0, req)
+            self._requeue(req)
             lost = True
         if lost:
             # the replacement decode pod starts cold; every re-admitted

@@ -94,10 +94,17 @@ with a typed :class:`~repro.serving.errors.Outcome`, counted in
 Fault injection is driven by :mod:`repro.runtime.chaos` (seeded,
 deterministic, replayable); ``ServeConfig.chaos`` activates it and the
 engine scopes the chaos engine around each tick.
+
+**Spans**: each tick runs in host spans (:mod:`repro.serving.spans`) —
+``engine.step`` holding ``engine.admit``,
+``engine.{prefill,decode}.{prepare,launch,wait}``, ``engine.sample`` and
+``engine.commit`` — that a profiler trace shows on the device's clock; the
+timed ones feed the ``*_time_s`` / ``*_wait_s`` counters of ``stats``.
 """
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from dataclasses import dataclass, field
 from typing import List, Optional
@@ -117,8 +124,18 @@ from repro.serving.errors import (AdmissionError, DeadlineExceeded,
                                   EngineCrash, KernelFault, Outcome,
                                   PagePoolExhausted)
 from repro.serving.paged import PagePool, PagedSeq, RadixCache
+from repro.serving.spans import Span
 
 PREFILL_CHUNK_CHOICES = (16, 32, 64, 128, 256, 512, 1024, 2048)
+
+
+def jit_step(fn, cfg: ModelConfig):
+    """``fn`` with ``cfg`` bound, jitted under ``fn``'s own name, so the
+    device trace's XLA module reads ``jit_<fn>`` (``jit_decode_step_paged``)
+    and tells the engine's steps apart."""
+    step = functools.partial(fn, cfg)
+    step.__name__ = fn.__name__
+    return jax.jit(step)
 
 
 @dataclass
@@ -129,13 +146,23 @@ class Request:
     rid: int = 0
     out_tokens: list = field(default_factory=list)
     done: bool = False
-    ttft_s: Optional[float] = None     # admission -> first generated token
     # --- resilience (PR 8) ----------------------------------------------
     outcome: Optional[str] = None      # Outcome.value once done
     error: str = ""                    # human-readable failure detail
     preemptions: int = 0               # times preempted + re-queued
-    t_submit: float = 0.0              # engine clock at submit
     resume_prompt: Optional[list] = None  # prompt + generated, for re-admit
+    # --- engine clock stamps ---------------------------------------------
+    t_submit: float = 0.0              # submit
+    t_enqueue: float = 0.0             # submit, or re-queue after preemption
+    t_admit: float = 0.0               # latest admission
+    t_first_token: Optional[float] = None
+
+    @property
+    def ttft_s(self) -> Optional[float]:
+        """Submit -> first generated token (queue wait included)."""
+        if self.t_first_token is None:
+            return None
+        return self.t_first_token - self.t_submit
 
 
 @dataclass(frozen=True)
@@ -175,7 +202,6 @@ class Slot:
         self.prefill_len = 0      # tokens to prefill (len(prompt) - 1)
         self.prefill_done = 0
         self.next_token = 0
-        self.t_admit = 0.0
 
     @property
     def tokens(self) -> list:
@@ -185,9 +211,8 @@ class Slot:
         ``_effective_prompt``."""
         return self.req.resume_prompt or self.req.prompt
 
-    def assign(self, req: Request, now: float):
+    def assign(self, req: Request):
         self.req = req
-        self.t_admit = now
         self.prefill_len = len(self.tokens) - 1
         self.prefill_done = 0
         if self.prefill_len == 0:
@@ -218,29 +243,32 @@ class Slot:
         return self.prefill_done if self.state == Slot.PREFILL else self.pos
 
 
+_REQ_STAMPS = ("t_submit", "t_enqueue", "t_admit", "t_first_token")
+
+
 def _req_state(req: Request) -> dict:
     """Pure-python deep copy of a request for crash-recovery snapshots."""
     return {"prompt": list(req.prompt),
             "max_new_tokens": req.max_new_tokens,
             "temperature": req.temperature, "rid": req.rid,
             "out_tokens": list(req.out_tokens), "done": req.done,
-            "ttft_s": req.ttft_s, "outcome": req.outcome,
+            "outcome": req.outcome,
             "error": req.error, "preemptions": req.preemptions,
-            "t_submit": req.t_submit,
             "resume_prompt": (None if req.resume_prompt is None
-                              else list(req.resume_prompt))}
+                              else list(req.resume_prompt)),
+            **{k: getattr(req, k) for k in _REQ_STAMPS}}
 
 
 def _req_from_state(d: dict) -> Request:
     req = Request(prompt=list(d["prompt"]),
                   max_new_tokens=d["max_new_tokens"],
                   temperature=d["temperature"], rid=d["rid"],
-                  out_tokens=list(d["out_tokens"]), done=d["done"],
-                  ttft_s=d["ttft_s"])
+                  out_tokens=list(d["out_tokens"]), done=d["done"])
     req.outcome = d["outcome"]
     req.error = d["error"]
     req.preemptions = d["preemptions"]
-    req.t_submit = d["t_submit"]
+    for k in _REQ_STAMPS:
+        setattr(req, k, d[k])
     req.resume_prompt = (None if d["resume_prompt"] is None
                          else list(d["resume_prompt"]))
     return req
@@ -276,8 +304,9 @@ class ServingEngine:
         B, S = serve_cfg.max_batch, serve_cfg.max_seq
         self.queue: List[Request] = []
         self.key = jax.random.PRNGKey(serve_cfg.seed)
-        self._decode = jax.jit(
-            lambda p, c, t, pos: lm.decode_step(cfg, p, c, t, pos))
+        self._decode = jit_step(lm.decode_step, cfg)
+        # token-mode prefill runs the decode step
+        self._token_prefill_step = self._decode
 
         mode = serve_cfg.prefill_mode
         if mode == "auto":
@@ -296,9 +325,7 @@ class ServingEngine:
         self.prefill_chunk = serve_cfg.prefill_chunk or min(S, max(
             1, planner.attention_plan(S, S, choices=PREFILL_CHUNK_CHOICES)))
         if mode == "batched":
-            self._prefill = jax.jit(
-                lambda p, c, t, pos, lens: lm.prefill_step(
-                    cfg, p, c, t, pos, lens))
+            self._prefill = jit_step(lm.prefill_step, cfg)
 
         self.paged = serve_cfg.kv_pages > 0
         if self.paged:
@@ -338,12 +365,8 @@ class ServingEngine:
             self.active: List[PagedSeq] = []
             self.slots: List[Slot] = []
             self._rr = 0                  # decode round-robin cursor
-            self._decode_paged = jax.jit(
-                lambda p, c, t, pos, bt: lm.decode_step_paged(
-                    cfg, p, c, t, pos, bt))
-            self._prefill_paged = jax.jit(
-                lambda p, c, t, pos, lens, bt: lm.prefill_step_paged(
-                    cfg, p, c, t, pos, lens, bt))
+            self._decode_paged = jit_step(lm.decode_step_paged, cfg)
+            self._prefill_paged = jit_step(lm.prefill_step_paged, cfg)
         else:
             self.cache = lm.init_cache(cfg, B, S)
             self.slots = [Slot(i) for i in range(B)]
@@ -368,15 +391,22 @@ class ServingEngine:
         self._tick = 0
         self._no_progress = 0
         self._admit_seq = 0        # monotonic admission number (preemption)
-        self._admitted = 0
         self._terminated = 0
         self._snapshots: List[dict] = []   # latest crash-recovery snapshot
         self.restored_requests: List[Request] = []  # set by restore()
 
         self._prefill_launches = 0   # per-trace GEMM launches of one chunk
+        self._step_s = 0.0           # the latest engine.step span
+        # Host-clock counters are summed from the engine.* spans: a
+        # dispatch's *_time_s is its launch + wait spans (+ sample for
+        # decode); step_time_s less the two waits is the tick's host work.
         self.stats = dict(prefill_dispatches=0, decode_dispatches=0,
                           prefill_tokens=0, decode_tokens=0,
                           prefill_time_s=0.0, decode_time_s=0.0,
+                          prefill_wait_s=0.0, decode_wait_s=0.0,
+                          sample_time_s=0.0, steps=0, step_time_s=0.0,
+                          admissions=0, queue_wait_s=0.0,
+                          first_tokens=0, first_token_s=0.0,
                           prefill_gemm_dispatches=0,
                           pages_used_peak=0, concurrency_peak=0,
                           prefix_hit_tokens=0,
@@ -386,6 +416,11 @@ class ServingEngine:
                           preemptions=0, watchdog_fired=0,
                           snapshots_taken=0,
                           **{f"outcome_{o.value}": 0 for o in Outcome})
+
+    def _span(self, name: str, *counters: str) -> Span:
+        """A host span named ``name`` that adds its duration to each of
+        ``counters`` in ``stats`` (see :mod:`repro.serving.spans`)."""
+        return Span(name, self.clock, self.stats, counters)
 
     def kv_cache_bytes(self) -> int:
         """Resident K/V bytes (pool pages in paged mode, the dense
@@ -423,8 +458,13 @@ class ServingEngine:
                    f"retry later")
             self._finish(req, Outcome.REJECTED_OVERLOAD, msg)
             raise AdmissionError(msg, Outcome.REJECTED_OVERLOAD)
-        req.t_submit = self.clock()
+        req.t_submit = req.t_enqueue = self.clock()
         self.queue.append(req)
+
+    def _stamp_admission(self, req: Request, now: float):
+        req.t_admit = now
+        self.stats["queue_wait_s"] += now - req.t_enqueue
+        self.stats["admissions"] += 1
 
     def _admit(self):
         if self.paged:
@@ -433,8 +473,9 @@ class ServingEngine:
         now = self.clock()
         for slot in self.slots:
             if slot.state == Slot.FREE and self.queue:
-                slot.assign(self.queue.pop(0), now)
-                self._admitted += 1
+                req = self.queue.pop(0)
+                self._stamp_admission(req, now)
+                slot.assign(req)
 
     def _effective_prompt(self, req: Request) -> list:
         """The token sequence this admission must make resident: a
@@ -484,10 +525,9 @@ class ServingEngine:
             seq.block_table[:m] = shared
             seq.block_table[m:m + len(pages)] = pages
             seq.n_shared = m
-            seq.t_admit = now
             seq.admit_idx = self._admit_seq
             self._admit_seq += 1
-            self._admitted += 1
+            self._stamp_admission(req, now)
             seq.prefill_done = m * self.page_size
             self.stats["prefix_hit_tokens"] += m * self.page_size
             if seq.prefill_done >= seq.prefill_len:
@@ -515,6 +555,13 @@ class ServingEngine:
                 self.pool.decref(pg)
         self.active.remove(seq)
 
+    def _release(self, s):
+        """Free a resident sequence: its pages, or its slot."""
+        if self.paged:
+            self._release_paged(s)
+        else:
+            s.release()
+
     def _count_prefill_launches(self, before: int):
         """Per-execution GEMM launch tally: substrate.DISPATCH_COUNTS is
         populated at jit-trace time, so the first dispatch's delta IS the
@@ -529,7 +576,7 @@ class ServingEngine:
         return np.asarray([s.write_pos for s in self.slots], np.int32)
 
     # -------------------------------------------------- guarded dispatch
-    def _guarded_dispatch(self, dispatch, rows):
+    def _guarded_dispatch(self, phase: str, dispatch, rows):
         """Run one jit'd step under the fault guards: retry (at most
         ``max_retries`` times) on a :class:`KernelFault` at the
         trace/launch boundary, and on non-finite logits in the active
@@ -539,14 +586,20 @@ class ServingEngine:
         spent and the caller must fail those rows' requests instead of
         sampling garbage.  A persistent KernelFault re-raises.
 
+        Each launch runs in the ``engine.<phase>.launch`` span; the
+        finiteness readback, the first sync after a decode launch, in
+        ``engine.<phase>.wait``.  Both add to ``<phase>_time_s``.
+
         ``self.cache`` is only assigned by the caller after this returns:
         the retry re-dispatches from the same pre-tick cache, so a
         recovered tick is bit-identical to a clean one (and the PRNG key
         is untouched — sampling happens after validation)."""
         retries = max(0, self.sc.max_retries)
+        total = f"{phase}_time_s"
         for attempt in range(retries + 1):
             try:
-                logits, new_cache = dispatch()
+                with self._span(f"engine.{phase}.launch", total):
+                    logits, new_cache = dispatch()
             except KernelFault:
                 if attempt < retries:
                     self.stats["kernel_fault_retries"] += 1
@@ -554,13 +607,16 @@ class ServingEngine:
                 raise
             if logits is None:           # prefill: nothing to sample
                 return None, new_cache, ()
-            if self._chaos is not None and self._chaos.fire("engine.sample"):
-                # corrupt to NaN on even draws, +Inf on odd (both must be
-                # caught by the same finiteness check)
-                n = self._chaos.chaos_draws["engine.sample"] - 1
-                logits = jnp.full_like(logits,
-                                       jnp.nan if n % 2 == 0 else jnp.inf)
-            finite = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
+            with self._span(f"engine.{phase}.wait", f"{phase}_wait_s",
+                            total):
+                if self._chaos is not None and self._chaos.fire(
+                        "engine.sample"):
+                    # corrupt to NaN on even draws, +Inf on odd (both
+                    # must be caught by the same finiteness check)
+                    n = self._chaos.chaos_draws["engine.sample"] - 1
+                    logits = jnp.full_like(
+                        logits, jnp.nan if n % 2 == 0 else jnp.inf)
+                finite = np.asarray(jnp.all(jnp.isfinite(logits), axis=-1))
             bad = tuple(r for r in rows if not bool(finite[r]))
             if bad and attempt < retries:
                 self.stats["sample_retries"] += 1
@@ -569,6 +625,44 @@ class ServingEngine:
         raise AssertionError("unreachable")
 
     # ------------------------------------------------------------ prefill
+    # A disaggregated engine prefills into its prefill pods' own cache and
+    # hands each prefilled sequence over: these hooks are what it replaces.
+    def _prefill_cache(self):
+        return self.cache
+
+    def _set_prefill_cache(self, cache):
+        self.cache = cache
+
+    def _prefilled(self, s):
+        """``s``'s prompt K/V is resident: publish its prefix pages."""
+        if self.paged:
+            self._publish_prefix(s)
+
+    def _prefill_dispatch(self, step, seqs, n_tokens: int, *args) -> bool:
+        """One prefill dispatch of ``step`` over the prefill cache, then
+        the existing ``block_until_ready`` in the ``engine.prefill.wait``
+        span.  A persistent KernelFault fails and releases ``seqs`` and
+        returns False."""
+        try:
+            _, cache, _ = self._guarded_dispatch(
+                "prefill",
+                lambda: (None, step(self.params, self._prefill_cache(),
+                                    *map(jnp.asarray, args))[1]),
+                rows=())
+        except KernelFault as exc:
+            for s in seqs:
+                self._finish(s.req, Outcome.FAILED,
+                             f"KernelFault during prefill: {exc}")
+                self._release(s)
+            return False
+        self._set_prefill_cache(cache)
+        with self._span("engine.prefill.wait", "prefill_wait_s",
+                        "prefill_time_s"):
+            jax.block_until_ready(cache)
+        self.stats["prefill_dispatches"] += 1
+        self.stats["prefill_tokens"] += n_tokens
+        return True
+
     def _prefill_tick(self):
         if self.paged:
             self._prefill_tick_paged()
@@ -580,107 +674,70 @@ class ServingEngine:
             for slot in pre:
                 self._prefill_token_by_token(slot)
             return
-        B, C = self.sc.max_batch, self.prefill_chunk
-        toks = np.zeros((B, C), np.int32)
-        pos = self._pos_vector()
-        lens = np.zeros(B, np.int32)
-        for s in pre:
-            c = min(C, s.prefill_len - s.prefill_done)
-            toks[s.index, :c] = s.tokens[s.prefill_done:
-                                         s.prefill_done + c]
-            lens[s.index] = c
-        t0 = self.clock()
-        d0 = sum(substrate.DISPATCH_COUNTS.values())
-        try:
-            _, self.cache, _ = self._guarded_dispatch(
-                lambda: (None, self._prefill(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(lens))[1]),
-                rows=())
-        except KernelFault as exc:
+        with self._span("engine.prefill.prepare"):
+            B, C = self.sc.max_batch, self.prefill_chunk
+            toks = np.zeros((B, C), np.int32)
+            pos = self._pos_vector()
+            lens = np.zeros(B, np.int32)
             for s in pre:
-                self._finish(s.req, Outcome.FAILED,
-                             f"KernelFault during prefill: {exc}")
-                s.release()
+                c = min(C, s.prefill_len - s.prefill_done)
+                toks[s.index, :c] = s.tokens[s.prefill_done:
+                                             s.prefill_done + c]
+                lens[s.index] = c
+        d0 = sum(substrate.DISPATCH_COUNTS.values())
+        if not self._prefill_dispatch(self._prefill, pre, int(lens.sum()),
+                                      toks, pos, lens):
             return
-        jax.block_until_ready(self.cache)
-        self.stats["prefill_time_s"] += self.clock() - t0
-        self.stats["prefill_dispatches"] += 1
-        self.stats["prefill_tokens"] += int(lens.sum())
         self._count_prefill_launches(d0)
         for s in pre:
             s.finish_chunk(int(lens[s.index]))
+            if s.state == Slot.DECODE:
+                self._prefilled(s)
 
     def _prefill_tick_paged(self):
         pre = [s for s in self.active if s.state == PagedSeq.PREFILL]
         if not pre:
             return
-        sel = pre[:self.sc.max_batch]
-        B, C = self.sc.max_batch, self.prefill_chunk
-        toks = np.zeros((B, C), np.int32)
-        pos = np.zeros(B, np.int32)
-        lens = np.zeros(B, np.int32)
-        bt = np.zeros((B, self.pages_per_seq), np.int32)
-        for r, s in enumerate(sel):
-            c = min(C, s.prefill_len - s.prefill_done)
-            toks[r, :c] = s.prompt[s.prefill_done:s.prefill_done + c]
-            pos[r] = s.prefill_done
-            lens[r] = c
-            bt[r] = s.block_table
-        t0 = self.clock()
+        with self._span("engine.prefill.prepare"):
+            sel = pre[:self.sc.max_batch]
+            B, C = self.sc.max_batch, self.prefill_chunk
+            toks = np.zeros((B, C), np.int32)
+            pos = np.zeros(B, np.int32)
+            lens = np.zeros(B, np.int32)
+            bt = np.zeros((B, self.pages_per_seq), np.int32)
+            for r, s in enumerate(sel):
+                c = min(C, s.prefill_len - s.prefill_done)
+                toks[r, :c] = s.prompt[s.prefill_done:s.prefill_done + c]
+                pos[r] = s.prefill_done
+                lens[r] = c
+                bt[r] = s.block_table
         d0 = sum(substrate.DISPATCH_COUNTS.values())
-        try:
-            _, self.cache, _ = self._guarded_dispatch(
-                lambda: (None, self._prefill_paged(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(lens),
-                    jnp.asarray(bt))[1]),
-                rows=())
-        except KernelFault as exc:
-            for s in sel:
-                self._finish(s.req, Outcome.FAILED,
-                             f"KernelFault during prefill: {exc}")
-                self._release_paged(s)
+        if not self._prefill_dispatch(self._prefill_paged, sel,
+                                      int(lens.sum()), toks, pos, lens, bt):
             return
-        jax.block_until_ready(self.cache)
-        self.stats["prefill_time_s"] += self.clock() - t0
-        self.stats["prefill_dispatches"] += 1
-        self.stats["prefill_tokens"] += int(lens.sum())
         self._count_prefill_launches(d0)
         for r, s in enumerate(sel):
             s.prefill_done += int(lens[r])
             if s.prefill_done >= s.prefill_len:
                 s.to_decode()
-                self._publish_prefix(s)
+                self._prefilled(s)
 
     def _prefill_token_by_token(self, slot: Slot):
         """Seed path: one full-batch decode dispatch per prompt token.
         Other slots' rows write garbage at their own next position, which
         their next real write overwrites before it is ever attended to."""
-        req = slot.req
         for i, t in enumerate(slot.tokens[:-1]):
-            toks = np.zeros(self.sc.max_batch, np.int32)
-            toks[slot.index] = t
-            pos_v = self._pos_vector()
-            pos_v[slot.index] = i
-            t0 = self.clock()
-            try:
-                _, self.cache, _ = self._guarded_dispatch(
-                    lambda tk=toks, pv=pos_v: (None, self._decode(
-                        self.params, self.cache, jnp.asarray(tk),
-                        jnp.asarray(pv))[1]),
-                    rows=())
-            except KernelFault as exc:
-                self._finish(req, Outcome.FAILED,
-                             f"KernelFault during prefill: {exc}")
-                slot.release()
+            with self._span("engine.prefill.prepare"):
+                toks = np.zeros(self.sc.max_batch, np.int32)
+                toks[slot.index] = t
+                pos_v = self._pos_vector()
+                pos_v[slot.index] = i
+            if not self._prefill_dispatch(self._token_prefill_step, [slot],
+                                          1, toks, pos_v):
                 return
-            jax.block_until_ready(self.cache)
-            self.stats["prefill_time_s"] += self.clock() - t0
-            self.stats["prefill_dispatches"] += 1
-            self.stats["prefill_tokens"] += 1
             slot.prefill_done = i + 1
         slot._to_decode()
+        self._prefilled(slot)
 
     # ------------------------------------------------- preemption (paged)
     def _youngest_other(self, s: PagedSeq) -> Optional[PagedSeq]:
@@ -700,6 +757,11 @@ class ServingEngine:
         self.stats["preemptions"] += 1
         req.resume_prompt = list(req.prompt) + list(req.out_tokens)
         self._release_paged(victim)
+        self._requeue(req)
+
+    def _requeue(self, req: Request):
+        """Put a preempted request back at the head of the queue."""
+        req.t_enqueue = self.clock()
         self.queue.insert(0, req)
 
     def _ensure_write_page(self, s: PagedSeq) -> bool:
@@ -748,74 +810,85 @@ class ServingEngine:
         self._finish(req, Outcome.PREEMPTED_RETRIED if req.preemptions
                      else Outcome.OK)
 
+    def _decode_dispatch(self, rows, temps, step, *args):
+        """One decode dispatch of ``step`` for ``rows`` ((batch row,
+        sequence) pairs), then sample (``engine.sample``) and commit the
+        tokens (``engine.commit``).  A persistent KernelFault fails and
+        releases every row's sequence."""
+        try:
+            logits, new_cache, bad = self._guarded_dispatch(
+                "decode",
+                lambda: step(self.params, self.cache,
+                             *map(jnp.asarray, args)),
+                rows=[r for r, _ in rows])
+        except KernelFault as exc:
+            for _, s in rows:
+                self._finish(s.req, Outcome.FAILED, f"KernelFault: {exc}")
+                self._release(s)
+            return
+        self.cache = new_cache
+        with self._span("engine.sample", "sample_time_s", "decode_time_s"):
+            nxt = self._sample(logits, jnp.asarray(temps))
+        self.stats["decode_dispatches"] += 1
+        self.stats["decode_tokens"] += len(rows)
+        with self._span("engine.commit"):
+            now = self.clock()
+            for r, s in rows:
+                req = s.req
+                if r in bad:
+                    self._finish(req, Outcome.FAILED,
+                                 "non-finite logits at sample time "
+                                 "(retry budget spent)")
+                    self._release(s)
+                    continue
+                tok = int(nxt[r])
+                if not req.out_tokens:
+                    req.t_first_token = now
+                    self.stats["first_token_s"] += now - req.t_admit
+                    self.stats["first_tokens"] += 1
+                req.out_tokens.append(tok)
+                s.next_token = tok
+                s.pos += 1
+                if (tok == self.sc.eos_id
+                        or len(req.out_tokens) >= req.max_new_tokens
+                        or s.pos >= self.sc.max_seq - 1):
+                    self._finish_stream(req)
+                    self._release(s)
+
     def _decode_tick_paged(self):
         dec = [s for s in self.active if s.state == PagedSeq.DECODE]
         if not dec:
             return
-        B = self.sc.max_batch
-        # round-robin: when more sequences are resident than dispatch rows,
-        # rotate so every sequence makes progress (no starvation)
-        start = self._rr % len(dec)
-        order = dec[start:] + dec[:start]
-        sel: List[PagedSeq] = []
-        for s in order:
-            if len(sel) >= B:
-                break
-            if s not in self.active:   # preempted/failed by earlier growth
-                continue
-            if self._ensure_write_page(s):
-                sel.append(s)
-        # growth may have preempted a sequence selected earlier this loop
-        sel = [s for s in sel if s in self.active]
-        if not sel:
-            return
-        self._rr += len(sel)
-        toks = np.zeros(B, np.int32)
-        temps = np.zeros(B, np.float32)
-        pos = np.zeros(B, np.int32)
-        bt = np.zeros((B, self.pages_per_seq), np.int32)
-        for r, s in enumerate(sel):
-            toks[r] = s.next_token
-            temps[r] = s.req.temperature
-            pos[r] = s.pos
-            bt[r] = s.block_table
-        t0 = self.clock()
-        try:
-            logits, new_cache, bad = self._guarded_dispatch(
-                lambda: self._decode_paged(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(pos), jnp.asarray(bt)),
-                rows=range(len(sel)))
-        except KernelFault as exc:
-            for s in sel:
-                self._finish(s.req, Outcome.FAILED, f"KernelFault: {exc}")
-                self._release_paged(s)
-            return
-        self.cache = new_cache
-        nxt = self._sample(logits, jnp.asarray(temps))
-        self.stats["decode_time_s"] += self.clock() - t0
-        self.stats["decode_dispatches"] += 1
-        self.stats["decode_tokens"] += len(sel)
-        now = self.clock()
-        for r, s in enumerate(sel):
-            req = s.req
-            if r in bad:
-                self._finish(req, Outcome.FAILED,
-                             "non-finite logits at sample time "
-                             "(retry budget spent)")
-                self._release_paged(s)
-                continue
-            tok = int(nxt[r])
-            if not req.out_tokens:
-                req.ttft_s = now - s.t_admit
-            req.out_tokens.append(tok)
-            s.next_token = tok
-            s.pos += 1
-            if (tok == self.sc.eos_id
-                    or len(req.out_tokens) >= req.max_new_tokens
-                    or s.pos >= self.sc.max_seq - 1):
-                self._finish_stream(req)
-                self._release_paged(s)
+        with self._span("engine.decode.prepare"):
+            B = self.sc.max_batch
+            # round-robin: when more sequences are resident than dispatch
+            # rows, rotate so every sequence makes progress (no starvation)
+            start = self._rr % len(dec)
+            order = dec[start:] + dec[:start]
+            sel: List[PagedSeq] = []
+            for s in order:
+                if len(sel) >= B:
+                    break
+                if s not in self.active:   # preempted/failed by growth
+                    continue
+                if self._ensure_write_page(s):
+                    sel.append(s)
+            # growth may have preempted a sequence selected earlier
+            sel = [s for s in sel if s in self.active]
+            if not sel:
+                return
+            self._rr += len(sel)
+            toks = np.zeros(B, np.int32)
+            temps = np.zeros(B, np.float32)
+            pos = np.zeros(B, np.int32)
+            bt = np.zeros((B, self.pages_per_seq), np.int32)
+            for r, s in enumerate(sel):
+                toks[r] = s.next_token
+                temps[r] = s.req.temperature
+                pos[r] = s.pos
+                bt[r] = s.block_table
+        self._decode_dispatch(list(enumerate(sel)), temps,
+                              self._decode_paged, toks, pos, bt)
 
     def _decode_tick(self):
         if self.paged:
@@ -824,48 +897,15 @@ class ServingEngine:
         dec = [s for s in self.slots if s.state == Slot.DECODE]
         if not dec:
             return
-        toks = np.zeros(self.sc.max_batch, np.int32)
-        temps = np.zeros(self.sc.max_batch, np.float32)
-        for s in dec:
-            toks[s.index] = s.next_token
-            temps[s.index] = s.req.temperature
-        t0 = self.clock()
-        try:
-            logits, new_cache, bad = self._guarded_dispatch(
-                lambda: self._decode(
-                    self.params, self.cache, jnp.asarray(toks),
-                    jnp.asarray(self._pos_vector())),
-                rows=[s.index for s in dec])
-        except KernelFault as exc:
+        with self._span("engine.decode.prepare"):
+            toks = np.zeros(self.sc.max_batch, np.int32)
+            temps = np.zeros(self.sc.max_batch, np.float32)
             for s in dec:
-                self._finish(s.req, Outcome.FAILED, f"KernelFault: {exc}")
-                s.release()
-            return
-        self.cache = new_cache
-        nxt = self._sample(logits, jnp.asarray(temps))
-        self.stats["decode_time_s"] += self.clock() - t0
-        self.stats["decode_dispatches"] += 1
-        self.stats["decode_tokens"] += len(dec)
-        now = self.clock()
-        for s in dec:
-            req = s.req
-            if s.index in bad:
-                self._finish(req, Outcome.FAILED,
-                             "non-finite logits at sample time "
-                             "(retry budget spent)")
-                s.release()
-                continue
-            tok = int(nxt[s.index])
-            if not req.out_tokens:
-                req.ttft_s = now - s.t_admit
-            req.out_tokens.append(tok)
-            s.next_token = tok
-            s.pos += 1
-            if (tok == self.sc.eos_id
-                    or len(req.out_tokens) >= req.max_new_tokens
-                    or s.pos >= self.sc.max_seq - 1):
-                self._finish_stream(req)
-                s.release()
+                toks[s.index] = s.next_token
+                temps[s.index] = s.req.temperature
+            pos = self._pos_vector()
+        self._decode_dispatch([(s.index, s) for s in dec], temps,
+                              self._decode, toks, pos)
 
     # ---------------------------------------------------------- deadlines
     def _deadline_reason(self, req: Request, now: float) -> str:
@@ -928,7 +968,7 @@ class ServingEngine:
         elif not self.paged:
             occ = [s for s in self.slots if s.state != Slot.FREE]
             if occ:
-                s = min(occ, key=lambda q: q.t_admit)
+                s = min(occ, key=lambda q: q.req.t_admit)
                 self._finish(s.req, Outcome.FAILED, msg)
                 s.release()
 
@@ -941,7 +981,7 @@ class ServingEngine:
     def _progress_sig(self):
         return (self.stats["prefill_dispatches"],
                 self.stats["decode_dispatches"],
-                self._admitted, self._terminated)
+                self.stats["admissions"], self._terminated)
 
     def step(self):
         """One engine tick: expire deadlines, admit, at most one prefill
@@ -950,20 +990,27 @@ class ServingEngine:
 
         Chaos point ``engine.tick``: an injected :class:`EngineCrash`
         raises out of here mid-stream; recover via ``restore()`` from
-        ``latest_snapshot()``."""
-        self._tick += 1
-        if self._chaos is not None and self._chaos.fire(
-                "engine.tick", f"tick={self._tick}"):
-            raise EngineCrash(
-                f"[chaos] engine killed at tick {self._tick} — restore "
-                f"from latest_snapshot() and rerun run_to_completion()")
-        with chaos_mod.scope(self._chaos):
-            return self._step_inner()
+        ``latest_snapshot()``.  The tick runs in the ``engine.step``
+        span, counted in ``steps`` and ``step_time_s``."""
+        with self._span("engine.step", "step_time_s") as span:
+            self.stats["steps"] += 1
+            self._tick += 1
+            if self._chaos is not None and self._chaos.fire(
+                    "engine.tick", f"tick={self._tick}"):
+                raise EngineCrash(
+                    f"[chaos] engine killed at tick {self._tick} — "
+                    f"restore from latest_snapshot() and rerun "
+                    f"run_to_completion()")
+            with chaos_mod.scope(self._chaos):
+                progress = self._step_inner()
+        self._step_s = span.s
+        return progress
 
     def _step_inner(self):
         sig0 = self._progress_sig()
         self._expire_deadlines()
-        self._admit()
+        with self._span("engine.admit"):
+            self._admit()
         if self._resident():
             self._prefill_tick()
             self._decode_tick()
@@ -974,11 +1021,10 @@ class ServingEngine:
         if self.sc.snapshot_every_ticks and not self._snapshots:
             self._take_snapshot()
         while (self.queue or self._resident()) and ticks < max_ticks:
-            t0 = self.clock()
             progress = self.step()
             # per-tick heartbeat: host 0's liveness + step time feed the
             # monitor an external supervisor would watch
-            self.monitor.beat(0, self._tick, self.clock() - t0)
+            self.monitor.beat(0, self._tick, self._step_s)
             ticks += 1
             if (self.sc.snapshot_every_ticks
                     and self._tick % self.sc.snapshot_every_ticks == 0):
@@ -1016,7 +1062,6 @@ class ServingEngine:
             "paged": self.paged,
             "tick": self._tick,
             "admit_seq": self._admit_seq,
-            "admitted": self._admitted,
             "terminated": self._terminated,
             "rr": self._rr,
             "key": np.asarray(self.key),
@@ -1034,8 +1079,7 @@ class ServingEngine:
                  "state": s.state, "pos": s.pos,
                  "prefill_len": s.prefill_len,
                  "prefill_done": s.prefill_done,
-                 "next_token": s.next_token, "t_admit": s.t_admit,
-                 "admit_idx": s.admit_idx}
+                 "next_token": s.next_token, "admit_idx": s.admit_idx}
                 for s in self.active]
             snap["pool"] = {"free_pages": list(self.pool.free_pages),
                             "refcounts": list(self.pool.refcounts)}
@@ -1046,7 +1090,7 @@ class ServingEngine:
                 {"state": s.state, "pos": s.pos,
                  "prefill_len": s.prefill_len,
                  "prefill_done": s.prefill_done,
-                 "next_token": s.next_token, "t_admit": s.t_admit,
+                 "next_token": s.next_token,
                  "req": _req_state(s.req) if s.req is not None else None}
                 for s in self.slots]
         return snap
@@ -1059,7 +1103,6 @@ class ServingEngine:
                              f"{'paged' if self.paged else 'dense'}")
         self._tick = snap["tick"]
         self._admit_seq = snap["admit_seq"]
-        self._admitted = snap["admitted"]
         self._terminated = snap["terminated"]
         self._rr = snap["rr"]
         self.key = jnp.asarray(snap["key"])
@@ -1088,7 +1131,6 @@ class ServingEngine:
                 seq.prefill_len = d["prefill_len"]
                 seq.prefill_done = d["prefill_done"]
                 seq.next_token = d["next_token"]
-                seq.t_admit = d["t_admit"]
                 seq.admit_idx = d["admit_idx"]
                 self.active.append(seq)
                 restored.append(req)
@@ -1099,7 +1141,6 @@ class ServingEngine:
                 slot.prefill_len = d["prefill_len"]
                 slot.prefill_done = d["prefill_done"]
                 slot.next_token = d["next_token"]
-                slot.t_admit = d["t_admit"]
                 slot.req = (_req_from_state(d["req"])
                             if d["req"] is not None else None)
                 if slot.req is not None:

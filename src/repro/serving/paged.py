@@ -309,7 +309,6 @@ class PagedSeq:
         self.prefill_len = len(self.prompt) - 1
         self.prefill_done = 0
         self.next_token = 0
-        self.t_admit = 0.0
         self.admit_idx = 0         # monotonic admission number (preemption
         #                            picks the youngest deterministically)
 

@@ -12,7 +12,9 @@ widths of the benchmark models:
 * the qwen3-moe-30b-a3b expert GEMMs (8 experts held, 2048 -> 768 and
   768 -> 2048) for fp32, int8 and w8a8;
 * one full-width qwen2-0.5b decode step and one prefill step under the
-  ``arrayflex`` backend.
+  ``arrayflex`` backend, and the engine's paged decode step, whose kernel
+  instructions must keep the names the chip benchmark reads and carry
+  their substrate site labels as named scopes.
 
 Each compiled program must hold the Pallas kernel (``tpu_custom_call``)
 and fit the chip's 16 GB of HBM.  Kernels pass ``interpret=False``
@@ -189,3 +191,41 @@ def test_qwen2_full_width_prefill_step_compiles(one_chip):
         lambda p, c, t, pos, n: lm.prefill_step(cfg, p, c, t, pos, n),
         (params, cache, _i32(one_chip, 4, 128), _i32(one_chip, 4),
          _i32(one_chip, 4)))
+
+
+def test_qwen2_paged_decode_kernels_keep_names_and_carry_sites(one_chip):
+    """The engine's paged decode step at full width: every kernel
+    instruction still reads as the chip benchmark's kernel events
+    (``_gemm.N`` / ``_expert_gemm.N``), and the substrate's site labels
+    reach the kernels' ``op_name`` metadata as named scopes."""
+    import re
+    import sys
+    from pathlib import Path
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]
+                           / "benchmarks" / "chip"))
+    from chipbench import harness, xtrace
+    from repro.serving.engine import jit_step
+
+    cfg, params, _ = _qwen2(one_chip)
+    pages, page, B = 64, 16, 4
+    cache = _shapes(one_chip, jax.eval_shape(
+        lambda: lm.init_paged_cache(cfg, pages, page)))
+    step = jit_step(lm.decode_step_paged, cfg)
+    args = (params, cache, _i32(one_chip, B), _i32(one_chip, B),
+            _i32(one_chip, B, 256 // page))
+    assert step.lower(*args).as_text().startswith(
+        "module @jit_decode_step_paged")
+    text = step.lower(*args).compile().as_text()
+    kernels = [ln.strip().removeprefix("ROOT ") for ln in text.splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    assert kernels
+    event = re.compile(harness.KERNEL_EVENT)
+    assert all(event.search(xtrace.op_name(k)) for k in kernels), \
+        [xtrace.op_name(k) for k in kernels if not
+         event.search(xtrace.op_name(k))]
+    scopes = {m.group(1) for k in kernels
+              for m in [re.search(r'op_name="[^"]*/([^/"]+)/jit\(_(?:expert_)?'
+                                  r'gemm\)/', k)] if m}
+    assert scopes == {"attn.wq", "attn.wk", "attn.wv", "attn.qk",
+                      "attn.pv", "attn.wo", "mlp.wi_gate+mlp.wi_up",
+                      "mlp.wo", "unembed"}, scopes
