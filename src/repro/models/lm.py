@@ -244,9 +244,10 @@ def attn_decode_paged(p, x, cfg: ModelConfig, cache, pos, block_tables,
     contract — then the write, mask, softmax and QK/PV dispatches are
     *identical* to the dense ``attn_decode`` vector-pos path, which is
     what keeps paged and dense greedy streams bit-identical.  Only the
-    written page scatters back: the engine's sharing invariant puts every
-    write position in a uniquely-owned page (aliased scratch rows collide
-    on page 0, which live rows never attend).
+    new token row scatters back, to row ``slot % page`` of its physical
+    page: the engine's sharing invariant puts every write position in a
+    uniquely-owned page (aliased scratch rows collide on page 0, which
+    live rows never attend).
     """
     cd = _cdtype(cfg)
     q, k_new, v_new = _proj_qkv(p, x, None, cfg, cd, norm_scale)
@@ -267,17 +268,10 @@ def attn_decode_paged(p, x, cfg: ModelConfig, cache, pos, block_tables,
     out = attn_lib.decode_attention(q, k_view, v_view, pos, window=0,
                                     backend=cfg.gemm_backend,
                                     interpret=cfg.pallas_interpret)
-    pg_idx = slot // page
-    phys = jnp.take_along_axis(bt, pg_idx[:, None], axis=1)[:, 0]
-    KV, hd = k_view.shape[2], k_view.shape[3]
-    sel = jnp.broadcast_to(pg_idx[:, None, None, None, None],
-                           (B, 1, page, KV, hd))
-    kpage = jnp.take_along_axis(
-        k_view.reshape(B, n_pg, page, KV, hd), sel, axis=1)[:, 0]
-    vpage = jnp.take_along_axis(
-        v_view.reshape(B, n_pg, page, KV, hd), sel, axis=1)[:, 0]
-    kp = cache["kp"].at[phys].set(kpage)
-    vp = cache["vp"].at[phys].set(vpage)
+    phys = jnp.take_along_axis(bt, (slot // page)[:, None], axis=1)[:, 0]
+    off = slot % page
+    kp = cache["kp"].at[phys, off].set(k_new[:, 0].astype(cache["kp"].dtype))
+    vp = cache["vp"].at[phys, off].set(v_new[:, 0].astype(cache["vp"].dtype))
     out = layers.linear(p["wo"], out.reshape(B, 1, -1), cd, site="attn.wo",
                         backend=cfg.gemm_backend,
                         interpret=cfg.pallas_interpret)

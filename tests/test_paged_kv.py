@@ -6,17 +6,22 @@ partition pages exactly, ``match`` returns the longest fully-paged
 published prefix (against a reference model), splits preserve lookups,
 and eviction only reclaims tree-only (refcount-1) leaves.  Engine tests
 check the admission contract: resident concurrency is bounded by the
-page budget, not ``max_batch``.
+page budget, not ``max_batch``.  The decode write-back test checks that a
+paged decode step changes exactly one token row per live sequence.
 """
 import random
 
 import jax
+import jax.numpy as jnp
+import numpy as np
 import pytest
 from hypothesis import given, settings, strategies as st
 
 from repro.configs import ARCHS, reduced
 from repro.core import planner
 from repro.models import lm
+from repro.nn import attention as attn_lib
+from repro.nn import layers
 from repro.serving import ServeConfig, ServingEngine
 from repro.serving.engine import Request
 from repro.serving.paged import PagePool, RadixCache
@@ -250,3 +255,97 @@ def test_planner_page_plan_divides_max_seq():
     # waste pressure: short expected lengths pull the page size down
     assert planner.page_plan(128, expected_len=8) <= \
         planner.page_plan(128, expected_len=128)
+
+
+# ------------------------------------------------------ decode write-back
+def _decode_paged_page_writeback(p, x, cfg, cache, pos, bt, norm_scale):
+    """The whole-page formula of the paged decode: take the written page
+    back out of the gathered view and scatter the page.  Returns the
+    attention output, both pools and the new K/V rows."""
+    cd = lm._cdtype(cfg)
+    q, k_new, v_new = lm._proj_qkv(p, x, None, cfg, cd, norm_scale)
+    B = x.shape[0]
+    positions = pos[:, None]
+    q = layers.apply_rope(q, positions, cfg.rope_theta)
+    k_new = layers.apply_rope(k_new, positions, cfg.rope_theta)
+    n_pg, page = bt.shape[1], cache["kp"].shape[1]
+    k_view = attn_lib.gather_pages(cache["kp"], bt)
+    v_view = attn_lib.gather_pages(cache["vp"], bt)
+    L = n_pg * page
+    slot = jnp.minimum(pos, L - 1)
+    hit = (jnp.arange(L)[None, :] == slot[:, None])[:, :, None, None]
+    k_view = jnp.where(hit, k_new.astype(cache["kp"].dtype), k_view)
+    v_view = jnp.where(hit, v_new.astype(cache["vp"].dtype), v_view)
+    out = attn_lib.decode_attention(q, k_view, v_view, pos, window=0,
+                                    backend=cfg.gemm_backend,
+                                    interpret=cfg.pallas_interpret)
+    pg_idx = slot // page
+    phys = jnp.take_along_axis(bt, pg_idx[:, None], axis=1)[:, 0]
+    KV, hd = k_view.shape[2], k_view.shape[3]
+    sel = jnp.broadcast_to(pg_idx[:, None, None, None, None],
+                           (B, 1, page, KV, hd))
+    kpage = jnp.take_along_axis(
+        k_view.reshape(B, n_pg, page, KV, hd), sel, axis=1)[:, 0]
+    vpage = jnp.take_along_axis(
+        v_view.reshape(B, n_pg, page, KV, hd), sel, axis=1)[:, 0]
+    out = layers.linear(p["wo"], out.reshape(B, 1, -1), cd, site="attn.wo",
+                        backend=cfg.gemm_backend,
+                        interpret=cfg.pallas_interpret)
+    return (out, cache["kp"].at[phys].set(kpage),
+            cache["vp"].at[phys].set(vpage), k_new[:, 0], v_new[:, 0])
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+def test_decode_writes_one_row_per_live_sequence(model, seed):
+    """``attn_decode_paged`` returns the input pool with exactly the rows
+    ``(phys[b], slot[b] % page)`` of live sequences replaced by their new
+    K/V, and the same attention output, bit for bit, as the whole-page
+    write-back.  Rows cover ``slot % page`` at 0 and ``page - 1``, the
+    first and the last page, a clamped ``pos >= L``, a prefix page shared
+    by two sequences, and two idle rows aliased to scratch page 0."""
+    cfg, params = model
+    page, n_pg, n_pages = 4, 4, 24
+    L = n_pg * page
+    # (pos, live): first page at offsets 0 and page-1, last page at
+    # offsets 0 and page-1, pos past L (clamped to L-1), two idle rows
+    rows = [(0, True), (page - 1, True), (L - page, True), (L - 1, True),
+            (L + 5, True), (0, False), (0, False)]
+    B = len(rows)
+    rng = np.random.default_rng(seed)
+    owned = rng.permutation(np.arange(1, n_pages))
+    bt = np.zeros((B, n_pg), np.int32)
+    for b, (_, live) in enumerate(rows):
+        if live:
+            bt[b] = owned[b * n_pg:(b + 1) * n_pg]
+    bt[3, 0] = bt[2, 0]                 # a prefix page neither row writes
+    pos = jnp.asarray([r[0] for r in rows], jnp.int32)
+    bt = jnp.asarray(bt)
+    KV, hd = cfg.n_kv_heads, cfg.resolved_head_dim
+    k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
+    cache = {"kp": jax.random.normal(k1, (n_pages, page, KV, hd),
+                                     jnp.bfloat16),
+             "vp": jax.random.normal(k2, (n_pages, page, KV, hd),
+                                     jnp.bfloat16)}
+    x = jax.random.normal(k3, (B, 1, cfg.d_model), lm._cdtype(cfg))
+    blk = jax.tree.map(lambda a: a[0], params["blocks"][0])
+    norm_scale = blk["ln1"]["scale"]
+
+    out, new = lm.attn_decode_paged(blk["attn"], x, cfg, cache, pos, bt,
+                                    norm_scale=norm_scale)
+    ref_out, ref_kp, ref_vp, k_new, v_new = _decode_paged_page_writeback(
+        blk["attn"], x, cfg, cache, pos, bt, norm_scale)
+    np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
+
+    slot = np.minimum(np.asarray(pos), L - 1)
+    live = np.array([r[1] for r in rows])
+    phys = np.asarray(bt)[np.arange(B), slot // page][live]
+    off = (slot % page)[live]
+    assert set(off) == {0, page - 1}
+    for name, rows_new, ref in (("kp", k_new, ref_kp), ("vp", v_new, ref_vp)):
+        want = np.array(cache[name])
+        want[phys, off] = np.asarray(rows_new.astype(jnp.bfloat16))[live]
+        got = np.asarray(new[name])
+        # scratch page 0 takes the idle rows' writes; live rows never
+        # attend it
+        np.testing.assert_array_equal(got[1:], want[1:])
+        np.testing.assert_array_equal(got[1:], np.asarray(ref)[1:])

@@ -14,7 +14,9 @@ widths of the benchmark models:
 * one full-width qwen2-0.5b decode step and one prefill step under the
   ``arrayflex`` backend, and the engine's paged decode step, whose kernel
   instructions must keep the names the chip benchmark reads and carry
-  their substrate site labels as named scopes.
+  their substrate site labels as named scopes, and which, at the 0.5b
+  offline cell's shape, writes its new K/V rows with no element-wise
+  gather of the written pages.
 
 Each compiled program must hold the Pallas kernel (``tpu_custom_call``)
 and fit the chip's 16 GB of HBM.  Kernels pass ``interpret=False``
@@ -229,3 +231,44 @@ def test_qwen2_paged_decode_kernels_keep_names_and_carry_sites(one_chip):
     assert scopes == {"attn.wq", "attn.wk", "attn.wv", "attn.qk",
                       "attn.pv", "attn.wo", "mlp.wi_gate+mlp.wi_up",
                       "mlp.wo", "unembed"}, scopes
+
+
+def _index_tuples(hlo_text):
+    """Number of index tuples of every gather and scatter in ``hlo_text``:
+    the index operand's elements over its index vector's length."""
+    import math
+    import re
+    dims = {m.group(1): [int(d) for d in m.group(2).split(",") if d]
+            for m in re.finditer(r"%([\w.\-]+) = \w+\[([\d,]*)\]",
+                                 hlo_text)}
+    counts = []
+    for m in re.finditer(r"= \S+ (?:gather|scatter)\(([^)]*)\)(.*)",
+                         hlo_text):
+        idx = dims[m.group(1).split(", ")[1].split("%")[-1]]
+        vdim = int(re.search(r"index_vector_dim=(\d+)", m.group(2)).group(1))
+        counts.append(math.prod(idx) // (idx[vdim] if vdim < len(idx) else 1))
+    return counts
+
+
+def test_qwen2_paged_decode_writes_back_rows_not_gathered_pages(one_chip):
+    """The paged decode step at the 0.5b offline cell's shape (B 128,
+    page 16, max_seq 2048) writes each new K/V row into the pool: no
+    gather or scatter takes one s32 index per element of the B written
+    pages (B x page x KV x hd), the element-wise gather that took the
+    written page back out of the gathered view."""
+    from repro.serving.engine import jit_step
+
+    cfg, params, _ = _qwen2(one_chip)
+    B, page, max_seq = 128, 16, 2048
+    cache = _shapes(one_chip, jax.eval_shape(
+        lambda: lm.init_paged_cache(cfg, 1025, page)))
+    args = (params, cache, _i32(one_chip, B), _i32(one_chip, B),
+            _i32(one_chip, B, max_seq // page))
+    text = jit_step(lm.decode_step_paged, cfg).lower(*args).compile() \
+        .as_text()
+    counts = _index_tuples(text)
+    # the embedding row gather, the page lookups, the two K/V views
+    # and the two row writes, once per layer in the scan body
+    assert len(counts) >= 6, counts
+    per_page = B * page * cfg.n_kv_heads * cfg.resolved_head_dim
+    assert max(counts) < per_page, (counts, per_page)
