@@ -6,6 +6,10 @@ A cell is found by its name in ``BENCHMARK.json``; its configuration is
 ``<file>`` of the named configuration, its traffic ``traffic/<mix>.json``
 and each per-layer metric ``metrics/<metric>.py``, all looked up by name, so
 a new cell, mix or metric is a new file and an entry, never an edit here.
+The configuration's ``reference`` key names its architecture module (a
+path from the checkout, e.g. ``benchmarks/chip/archs/qwen2.py``), which
+holds everything that knows what a layer is: the names in ``ARCH_NAMES``.
+So a new architecture is a new module too.
 
 The harness drives the program only through ``repro.configs``,
 ``repro.models.lm`` (the parameter layout) and ``ServingEngine.submit`` /
@@ -37,13 +41,14 @@ CHECKOUT = BENCH_DIR.parents[1]
 # qk/pv calls), as read by hand from a TPU v5e trace (PERF.md).
 KERNEL_EVENT = r"^_(expert_)?gemm\.\d+ custom-call "
 TRACE_S = 4.0
-HF_TO_PROGRAM = {
-    "num_hidden_layers": "n_layers", "hidden_size": "d_model",
-    "num_attention_heads": "n_heads", "num_key_value_heads": "n_kv_heads",
-    "intermediate_size": "d_ff", "vocab_size": "vocab_size",
-    "rope_theta": "rope_theta", "rms_norm_eps": "rms_eps",
-    "tie_word_embeddings": "tie_embeddings",
-}
+# what an architecture module exports: its sizes from the configuration file
+# (``dims``), the program's ModelConfig fields they state
+# (``program_kwargs``), the reference's arguments (``reference_kwargs``),
+# one layer's leaves (``LAYER_LEAVES``) and plain float32 layer (``layer``),
+# and the counts behind the per-layer shares (the rest)
+ARCH_NAMES = ("dims", "program_kwargs", "reference_kwargs", "LAYER_LEAVES",
+              "layer", "kernel_calls_per_dispatch", "step_least_time",
+              "model_flops")
 
 
 # (start, end, function) of every compilation, on the wall clock
@@ -89,40 +94,49 @@ def load_cell(bench: dict, workload: str, root: Path = BENCH_DIR,
     per_layer = [m for m in bench["per_layer"]
                  if here(m) and m["moves"] in reported]
     return SimpleNamespace(name=workload, cell=cell, conf=conf, mix=mix,
-                           e2e=e2e, per_layer=per_layer, root=root)
+                           e2e=e2e, per_layer=per_layer, root=root,
+                           arch=load_arch(home / conf["reference"]))
+
+
+def _load_module(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(
+        name.replace(".", "_").replace("-", "_"), path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def load_reader(root: Path, name: str):
-    path = root / "metrics" / f"{name}.py"
-    spec = importlib.util.spec_from_file_location(
-        f"chipbench_metric_{name.replace('.', '_').replace('-', '_')}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load_module(root / "metrics" / f"{name}.py",
+                        f"chipbench_metric_{name}").read
 
 
-def dims_of(conf: dict) -> dict:
-    return dict(layers=conf["num_hidden_layers"], hidden=conf["hidden_size"],
-                heads=conf["num_attention_heads"],
-                kv_heads=conf["num_key_value_heads"],
-                head_dim=conf["hidden_size"] // conf["num_attention_heads"],
-                ffn=conf["intermediate_size"], vocab=conf["vocab_size"])
+def load_arch(path: Path):
+    """The architecture module at ``path``; it must export ``ARCH_NAMES``."""
+    mod = _load_module(path, f"chipbench_arch_{Path(path).stem}")
+    missing = [n for n in ARCH_NAMES if not hasattr(mod, n)]
+    if missing:
+        raise AttributeError(f"architecture module {path} lacks {missing}")
+    return mod
 
 
-def model_config(conf: dict, overrides: dict | None = None):
+def model_config(conf: dict, arch, overrides: dict | None = None):
     """The program's ModelConfig: its own published config, replaced by
-    every size of the configuration file (and by ``overrides``, which a
-    control uses to switch the program to a lower precision)."""
+    every size of the configuration file that the architecture module maps
+    (and by ``overrides``, which a control uses to switch the program to a
+    lower precision).  A dict given for a field that is itself a config
+    (an expert group, say) replaces those fields of the program's own."""
     from repro.configs import get_config
     prog = conf["program"]
-    kw = {HF_TO_PROGRAM[k]: conf[k] for k in HF_TO_PROGRAM}
-    kw.update(head_dim=conf["hidden_size"] // conf["num_attention_heads"],
-              qkv_bias=prog["qkv_bias"], sliding_window=0,
-              param_dtype=prog["param_dtype"],
+    base = get_config(prog["config"])
+    kw = arch.program_kwargs(conf)
+    kw.update(param_dtype=prog["param_dtype"],
               compute_dtype=prog["compute_dtype"],
               gemm_backend=prog["gemm_backend"])
     kw.update(overrides or {})
-    cfg = dataclasses.replace(get_config(prog["config"]), **kw)
+    kw = {k: dataclasses.replace(getattr(base, k), **v)
+          if isinstance(v, dict) else v for k, v in kw.items()}
+    cfg = dataclasses.replace(base, **kw)
     for k, v in kw.items():
         if getattr(cfg, k) != v:
             raise ValueError(f"{k}: program holds {getattr(cfg, k)!r}, "
@@ -226,14 +240,14 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     mix = dict(c.mix)
     if rate is not None:
         mix["rate"] = rate
-    conf, e = c.conf, mix["engine"]
-    cfg = model_config(conf, overrides)
-    dims = dims_of(conf)
+    conf, e, arch = c.conf, mix["engine"], c.arch
+    cfg = model_config(conf, arch, overrides)
+    dims = arch.dims(conf)
     expected = jax.eval_shape(lambda: lm.init_params(cfg, jax.random.PRNGKey(0)))
     log(f"[{workload}] set-up: JAX and program imported at "
         f"{clock() - t_process:.1f} s")
     params = weights.program_params(expected, dims, seed, cfg.padded_vocab,
-                                    cfg.tie_embeddings)
+                                    cfg.tie_embeddings, arch.LAYER_LEAVES)
     jax.block_until_ready(params)
     log(f"[{workload}] set-up: weights made at {clock() - t_process:.1f} s")
     sc = ServeConfig(max_batch=e["max_batch"], max_seq=e["max_seq"],
@@ -331,7 +345,7 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
         device["window_s"] = red["window_s"]
         ctx = SimpleNamespace(
             stats=window["trace_stats"], dims=dims, engine=e, peak=peak,
-            trace=red, prefill_dispatches=trk.prefill_dispatches,
+            arch=arch, trace=red, prefill_dispatches=trk.prefill_dispatches,
             decode_dispatches=trk.decode_dispatches)
         per_layer = {}
         for m in c.per_layer:
@@ -354,9 +368,8 @@ def run_cell(bench: dict, workload: str, seed: int, seconds: float,
     gc.collect()
     t_ref = clock()
     per_req = reference.served_gaps(
-        dims, seed, [(it.prompt, o) for it, o in served],
-        eps=conf["rms_norm_eps"], theta=conf["rope_theta"],
-        padded_vocab=cfg.padded_vocab, tied=conf["tie_word_embeddings"])
+        arch, dims, seed, [(it.prompt, o) for it, o in served],
+        padded_vocab=cfg.padded_vocab, **arch.reference_kwargs(conf))
     gaps = np.concatenate(per_req) if per_req else np.zeros(0)
     lim = conf["correct"]
     known = gaps[np.isfinite(gaps)]

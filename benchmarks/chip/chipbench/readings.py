@@ -1,12 +1,11 @@
 """The arithmetic behind the per-layer metrics.  Each metric file under
 ``metrics/`` names one of these as its ``read``.  A reading takes the traced
 interval's context (engine counter deltas, the live rows of each dispatch,
-the reduced device trace, the peaks) and returns a number, or None where the
-interval holds nothing to read.  A share of a roofline or a peak is never
-returned as 0 for lack of data."""
+the reduced device trace, the peaks, and ``arch``, the configuration's
+architecture module, which counts its own work) and returns a number, or
+None where the interval holds nothing to read.  A share of a roofline or a
+peak is never returned as 0 for lack of data."""
 from __future__ import annotations
-
-from chipbench import roofline
 
 
 def _ratio(num, den, scale=100.0):
@@ -35,8 +34,7 @@ def decode_step_ms(ctx):
 
 
 def expected_kernel_calls(ctx):
-    per_step = len(roofline.SITES) - 1
-    return (per_step * ctx.dims["layers"] + 1) * (
+    return ctx.arch.kernel_calls_per_dispatch(ctx.dims) * (
         len(ctx.prefill_dispatches) + len(ctx.decode_dispatches))
 
 
@@ -51,10 +49,10 @@ def gemm_roofline(ctx):
     B, C = ctx.engine["max_batch"], ctx.engine["prefill_chunk"]
     least = 0.0
     for rows in ctx.prefill_dispatches:
-        least += roofline.step_least_time(
+        least += ctx.arch.step_least_time(
             ctx.dims, ctx.peak, B * C, B, [(C, p + n) for p, n in rows])
     for live in ctx.decode_dispatches:
-        least += roofline.step_least_time(
+        least += ctx.arch.step_least_time(
             ctx.dims, ctx.peak, B, B, [(1, c) for c in live])
     return _ratio(least, red["kernel_s"])
 
@@ -64,9 +62,9 @@ def step_mfu(ctx):
     chip's bf16 peak."""
     if not (ctx.prefill_dispatches or ctx.decode_dispatches):
         return None
-    flops = roofline.model_flops(
+    flops = ctx.arch.model_flops(
         ctx.dims, [r for rows in ctx.prefill_dispatches for r in rows],
-        [c for live in ctx.decode_dispatches for c in live])
+        [c for live in ctx.decode_dispatches for c in live], ctx.stats)
     return _ratio(flops, ctx.trace["window_s"]
                   * ctx.peak["bf16_flops_per_s"])
 

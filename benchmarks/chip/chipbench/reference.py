@@ -1,15 +1,12 @@
-"""Plain float32 reference of the Qwen2 decoder, in jax.numpy alone.
+"""The plain float32 reference's shared part, in jax.numpy alone: the
+building blocks most decoders share (RMSNorm, rotary embedding), the
+blocking of served rows, and the gap of each served token below the
+reference's best logit after the final RMSNorm and the head.
 
-It follows the published Qwen2 equations (Hugging Face ``modeling_qwen2``):
-token embedding; per layer RMSNorm, Q/K/V projections with bias, rotary
-position embedding over rotate-half pairs, causal grouped-query attention
-with 1/sqrt(head_dim) scaling and a float32 softmax, output projection
-without bias, residual, RMSNorm, SwiGLU MLP (silu(x Wg) * (x Wu)) Wd,
-residual; a final RMSNorm and logits against the tied embedding or the
-untied head.  Every product runs at ``Precision.HIGHEST`` (true float32 on
-a TPU).  Departures: none in the equations; the weights are the
-benchmark's seeded random weights (``weights.py``), and a sliced vocabulary
-is a smaller vocabulary.
+A layer's own equations are its architecture module's ``layer``
+(``archs/<name>.py``, named by the configuration file's ``reference`` key),
+which states what it follows and its departures.  Every product runs at
+``Precision.HIGHEST`` (true float32 on a TPU).
 
 It imports nothing of the program.  It runs layer by layer over blocks of
 sequences padded to a few lengths, so that it fits in the chip's memory
@@ -18,7 +15,6 @@ once the program's state is freed.
 from __future__ import annotations
 
 import functools
-import math
 
 import jax
 import jax.numpy as jnp
@@ -42,38 +38,6 @@ def rope(x, theta):
     sin = jnp.asarray(np.sin(ang), jnp.float32)[None, :, None, :]
     x1, x2 = jnp.split(x, 2, axis=-1)
     return jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], -1)
-
-
-def _layer(x, w, *, dims, eps, theta, q_block):
-    """One decoder layer on x (N, L, d)."""
-    N, L, d = x.shape
-    H, KV, hd = dims["heads"], dims["kv_heads"], dims["head_dim"]
-    g = H // KV
-
-    def mm(a, b):
-        return jnp.einsum("...k,kn->...n", a, b, precision=HI)
-
-    h = rmsnorm(x, w["ln1/scale"], eps)
-    q = (mm(h, w["attn/wq/w"]) + w["attn/wq/b"]).reshape(N, L, H, hd)
-    k = (mm(h, w["attn/wk/w"]) + w["attn/wk/b"]).reshape(N, L, KV, hd)
-    v = (mm(h, w["attn/wv/w"]) + w["attn/wv/b"]).reshape(N, L, KV, hd)
-    q, k = rope(q, theta), rope(k, theta)
-    q = q.reshape(N, L, KV, g, hd)
-    outs = []
-    for s in range(0, L, q_block):
-        qb = q[:, s:s + q_block]
-        sc = jnp.einsum("nqkgd,ntkd->nkgqt", qb, k, precision=HI)
-        sc = sc / math.sqrt(hd)
-        mask = (jnp.arange(L)[None, :]
-                <= (s + jnp.arange(qb.shape[1]))[:, None])
-        sc = jnp.where(mask, sc, -jnp.inf)
-        p = jax.nn.softmax(sc, axis=-1)
-        outs.append(jnp.einsum("nkgqt,ntkd->nqkgd", p, v, precision=HI))
-    o = jnp.concatenate(outs, axis=1).reshape(N, L, H * hd)
-    x = x + mm(o, w["attn/wo/w"])
-    h = rmsnorm(x, w["ln2/scale"], eps)
-    a = jax.nn.silu(mm(h, w["mlp/wi_gate/w"])) * mm(h, w["mlp/wi_up/w"])
-    return x + mm(a, w["mlp/wo/w"])
 
 
 def _blocks(lengths, budget):
@@ -105,12 +69,16 @@ def _gaps_at(x, rows, pos, tok, scale, head, *, eps):
     return jnp.where(ok, lg.max(axis=1) - picked, jnp.inf)
 
 
-def served_gaps(dims, seed, served, *, eps, theta, padded_vocab, tied,
-                budget=1 << 14, chunk=1024):
+def served_gaps(arch, dims, seed, served, *, eps, padded_vocab, tied,
+                budget=1 << 14, chunk=1024, **layer_kw):
     """For each (prompt, output tokens) pair, the gaps by which its served
     tokens' reference logits lie below the reference's best logit at their
     positions (inf for a token outside the vocabulary): one float64 array
     per pair, in order.
+
+    ``arch`` is the architecture module: its ``LAYER_LEAVES`` and its
+    ``layer``, which takes ``eps`` and ``layer_kw`` (the module's
+    ``reference_kwargs`` give all of them, with ``tied``).
 
     Rows run in blocks of about ``budget`` positions; each layer's weights
     are made once and applied to every block, and only the gaps come back
@@ -128,7 +96,8 @@ def served_gaps(dims, seed, served, *, eps, theta, padded_vocab, tied,
         for j, i in enumerate(idx):
             tok[j, :len(rows[i])] = rows[i]
         blocks.append((idx, top["embed/table"][jnp.asarray(tok)]))
-    make = jax.jit(functools.partial(weights.layer_weights, dims=dims))
+    make = jax.jit(functools.partial(weights.layer_weights, dims=dims,
+                                     leaves=arch.LAYER_LEAVES))
     layers = {}
     for li in range(dims["layers"]):
         w = make(root, layer=li)
@@ -138,8 +107,8 @@ def served_gaps(dims, seed, served, *, eps, theta, padded_vocab, tied,
                 q_block = max(64, min(512, (1 << 30) // (
                     n * dims["heads"] * L * 4) // 64 * 64))
                 layers[n, L] = jax.jit(functools.partial(
-                    _layer, dims=dims, eps=eps, theta=theta,
-                    q_block=q_block))
+                    arch.layer, dims=dims, eps=eps, q_block=q_block,
+                    **layer_kw))
             blocks[b] = (idx, layers[n, L](x, w))
         del w
     v = dims["vocab"]

@@ -4,7 +4,9 @@ The same function makes each layer's weights for the program (all layers in
 one jitted call on the device, stacked in the program's parameter layout)
 and for the plain reference (one layer at a time, after the program's state
 is freed).  A leaf's values depend only on (seed, label, layer), so the two
-agree without the reference ever reading what the program holds.
+agree without the reference ever reading what the program holds.  Which
+leaves a layer has, and their shapes, is the architecture module's
+``LAYER_LEAVES`` table (label -> shape from dims), handed in as ``leaves``.
 
 Scales follow the usual initialisation (linear weights N(0, 1/fan_in),
 embedding N(0, 0.02^2)); biases and norm scales are drawn too, so a path
@@ -19,21 +21,6 @@ import zlib
 import jax
 import jax.numpy as jnp
 
-# label -> (program path inside one stacked block, shape from dims)
-LAYER_LEAVES = {
-    "ln1/scale": lambda d: (d["hidden"],),
-    "attn/wq/w": lambda d: (d["hidden"], d["heads"] * d["head_dim"]),
-    "attn/wq/b": lambda d: (d["heads"] * d["head_dim"],),
-    "attn/wk/w": lambda d: (d["hidden"], d["kv_heads"] * d["head_dim"]),
-    "attn/wk/b": lambda d: (d["kv_heads"] * d["head_dim"],),
-    "attn/wv/w": lambda d: (d["hidden"], d["kv_heads"] * d["head_dim"]),
-    "attn/wv/b": lambda d: (d["kv_heads"] * d["head_dim"],),
-    "attn/wo/w": lambda d: (d["heads"] * d["head_dim"], d["hidden"]),
-    "ln2/scale": lambda d: (d["hidden"],),
-    "mlp/wi_gate/w": lambda d: (d["hidden"], d["ffn"]),
-    "mlp/wi_up/w": lambda d: (d["hidden"], d["ffn"]),
-    "mlp/wo/w": lambda d: (d["ffn"], d["hidden"]),
-}
 BIAS_SCALE = 0.2
 NORM_SPREAD = 0.1
 
@@ -54,7 +41,9 @@ def _leaf(key, label: str, shape, valid_rows=None):
     if label == "lm_head/w":
         w = z / jnp.sqrt(float(shape[0]))
         return jnp.where(jnp.arange(shape[1])[None, :] < valid_rows, w, 0.0)
-    return z / jnp.sqrt(float(shape[0]))
+    # fan-in is the next-to-last axis: a stack of experts' matrices takes
+    # the fan-in of one
+    return z / jnp.sqrt(float(shape[-2]))
 
 
 def _key(root, label: str, layer: int):
@@ -62,10 +51,10 @@ def _key(root, label: str, layer: int):
         label.encode())), layer)
 
 
-def layer_weights(root, dims: dict, layer):
+def layer_weights(root, dims: dict, layer, leaves: dict):
     """One layer's weights as {label: array}; ``layer`` may be traced."""
     return {lb: _leaf(_key(root, lb, layer), lb, fn(dims))
-            for lb, fn in LAYER_LEAVES.items()}
+            for lb, fn in leaves.items()}
 
 
 def top_weights(root, dims: dict, padded_vocab: int, tied: bool):
@@ -101,15 +90,16 @@ def _paths(tree, prefix=""):
 
 
 def program_params(expected, dims: dict, seed: int, padded_vocab: int,
-                   tied: bool):
+                   tied: bool, leaves: dict):
     """The program's whole parameter tree, made on the device in one jitted
     call.  ``expected`` is the tree of shapes the program's own initialiser
     gives (``jax.eval_shape``); the layout made here must match it leaf for
-    leaf, or the program has changed under the benchmark."""
+    leaf, or the program and the architecture module (``leaves``, ``dims``)
+    disagree."""
     n_layers = dims["layers"]
 
     def make(root):
-        stacked = jax.vmap(lambda l: layer_weights(root, dims, l))(
+        stacked = jax.vmap(lambda l: layer_weights(root, dims, l, leaves))(
             jnp.arange(n_layers))
         tree = {"blocks": [{}]}
         for lb, arr in stacked.items():
@@ -122,10 +112,13 @@ def program_params(expected, dims: dict, seed: int, padded_vocab: int,
     root = root_key(seed)      # an argument, so one compiled program serves
     got = dict(_paths(jax.eval_shape(make, root)))  # every seed
     want = dict(_paths(expected))
-    if set(got) != set(want) or any(
-            (got[p].shape, got[p].dtype) != (want[p].shape, want[p].dtype)
-            for p in want):
+
+    def leaf(tree, p):
+        return f"{tree[p].dtype}{list(tree[p].shape)}" if p in tree else "-"
+    differ = [(p, leaf(want, p), leaf(got, p)) for p in sorted(
+        set(got) | set(want)) if leaf(want, p) != leaf(got, p)]
+    if differ:
         raise RuntimeError(
-            "the program's parameter layout differs from the benchmark's: "
-            f"{sorted((p, w.shape, str(w.dtype)) for p, w in want.items())}")
+            "the program's parameter layout differs from the benchmark's; "
+            f"(path, program's leaf, benchmark's leaf): {differ}")
     return jax.jit(make)(root)

@@ -33,6 +33,17 @@ def load_bench():
     return json.loads((REPO / "BENCHMARK.json").read_text())
 
 
+def mirror(home, rel):
+    """Copy the checkout's file or directory ``rel`` to the same place under
+    ``home``, so that a configuration's ``reference`` path resolves there."""
+    src, dst = REPO / rel, home / rel
+    dst.parent.mkdir(parents=True, exist_ok=True)
+    if src.is_dir():
+        shutil.copytree(src, dst)
+    else:
+        shutil.copy(src, dst)
+
+
 @pytest.fixture
 def tiny_home(tmp_path, monkeypatch):
     """A benchmark home with one tiny cell, ``tiny.chat``, served by the
@@ -48,6 +59,7 @@ def tiny_home(tmp_path, monkeypatch):
     for d in ("configs", "traffic"):
         (tmp_path / d).mkdir()
     shutil.copytree(BENCH / "metrics", tmp_path / "metrics")
+    mirror(tmp_path, "benchmarks/chip/archs")
     shutil.copy(BENCH / "peaks.json", tmp_path / "peaks.json")
     conf = json.loads((BENCH / "configs" / "qwen2-0.5b.json").read_text())
     conf.update(TINY_SIZES)

@@ -11,9 +11,10 @@ import pytest
 
 from conftest import BENCH, REPO, load_bench
 
-from chipbench import reference, roofline, traffic, weights, xtrace
+from chipbench import harness, reference, roofline, traffic, weights, xtrace
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
+qwen2 = harness.load_arch(BENCH / "archs" / "qwen2.py")
 
 
 def test_benchmark_json_names_files_and_metrics():
@@ -31,6 +32,14 @@ def test_benchmark_json_names_files_and_metrics():
         assert conf["name"] == c["name"] and conf["source"] == c["source"]
         assert conf["reduced"] == c["reduced"]
         assert all(k in conf["published"] for k in c["reduced"])
+        # the architecture module it names lies under the benchmark's paths,
+        # exists and exports the contract
+        assert any(conf["reference"].startswith(d + "/") for d in b["paths"])
+        arch = harness.load_arch(REPO / conf["reference"])
+        assert all(callable(getattr(arch, n)) for n in harness.ARCH_NAMES
+                   if n != "LAYER_LEAVES")
+        assert arch.LAYER_LEAVES and set(arch.dims(conf)) >= {
+            "layers", "hidden", "heads", "head_dim", "vocab"}
     e2e = {m["name"]: m for m in b["end_to_end"]}
     assert e2e["setup_s"]["bound"] <= 0.25
     assert all(0.01 <= m["bound"] <= 0.25 for m in e2e.values())
@@ -127,30 +136,48 @@ QWEN25 = dict(layers=6, hidden=5120, heads=40, kv_heads=8, head_dim=128,
 
 def test_site_flops_and_bytes_by_hand():
     # qwen2-0.5b attn.wq at 4 decode rows: (4, 896) @ (896, 896), bf16
-    f, b = roofline.site_cost(QWEN2, "attn.wq", 4)
+    f, b = qwen2.site_cost(QWEN2, "attn.wq", 4)
     assert f == 2 * 4 * 896 * 896
     assert b == 2 * (4 * 896 + 896 * 896) + 2 * 4 * 896
     # qwen2.5-14b mlp.wi (gate and up) at 12 x 256 prefill rows
-    f, b = roofline.site_cost(QWEN25, "mlp.wi", 3072)
+    f, b = qwen2.site_cost(QWEN25, "mlp.wi", 3072)
     assert f == 2 * 2 * 3072 * 5120 * 13824
     assert b == 2 * (3072 * 5120 + 2 * 5120 * 13824) + 2 * 3072 * 13824
     # qwen2.5-14b qk for one row of 256 queries over 1000 live keys:
     # 8 kv heads x (5*256, 128) @ (128, 1000), fp32 scores
-    f, b = roofline.site_cost(QWEN25, "attn.qk", 0, [(256, 1000)])
+    f, b = qwen2.site_cost(QWEN25, "attn.qk", 0, [(256, 1000)])
     assert f == 8 * 2 * (5 * 256) * 128 * 1000
     assert b == 8 * (2 * (5 * 256 * 128 + 128 * 1000) + 4 * 5 * 256 * 1000)
     # tied head: (rows, 896) @ (896, 151936) with fp32 logits
-    f, b = roofline.site_cost(QWEN2, "unembed", 2)
+    f, b = qwen2.site_cost(QWEN2, "unembed", 2)
     assert f == 2 * 2 * 896 * 151936
     # one decode token at context 100: 2 x matmul params x layers,
     # 4 x heads x head_dim x context x layers of attention, and the logits
     per_layer = 896 * 896 + 2 * 896 * 128 + 896 * 896 + 3 * 896 * 4864
     want = (2 * per_layer * 24 + 4 * 14 * 64 * 100 * 24
             + 2 * 896 * 151936)
-    assert roofline.model_flops(QWEN2, [], [100]) == want
+    assert qwen2.model_flops(QWEN2, [], [100], {}) == want
     # a prefill chunk of 3 tokens at positions 5, 6, 7: contexts 6, 7, 8
-    got = roofline.model_flops(QWEN2, [(5, 3)], [])
+    got = qwen2.model_flops(QWEN2, [(5, 3)], [], {})
     assert got == 3 * 2 * per_layer * 24 + 4 * 14 * 64 * 24 * (6 + 7 + 8)
+
+
+def test_a_module_may_replace_fields_of_a_nested_program_group():
+    """An architecture module states a nested group of the program's config
+    (the experts of an MoE layer) as a dict; the harness replaces those
+    fields of the program's own group and keeps the rest."""
+    from types import SimpleNamespace
+    conf = {"program": {"config": "qwen3-moe-30b-a3b",
+                        "param_dtype": "float32", "compute_dtype": "bfloat16",
+                        "gemm_backend": "arrayflex"}}
+    arch = SimpleNamespace(program_kwargs=lambda c: {
+        "n_layers": 4, "moe": {"num_experts": 8}})
+    cfg = harness.model_config(conf, arch)
+    assert (cfg.n_layers, cfg.moe.num_experts, cfg.moe.top_k,
+            cfg.moe.expert_d_ff) == (4, 8, 8, 768)
+    arch = SimpleNamespace(program_kwargs=lambda c: {"moe": {"experts": 8}})
+    with pytest.raises(TypeError):
+        harness.model_config(conf, arch)
 
 
 def test_peak_table_is_keyed_by_device_kind(tmp_path):
@@ -167,9 +194,9 @@ def test_weights_each_layer_matches_its_stacked_slice():
                 ffn=48, vocab=100)
     root = weights.root_key(2**33 + 9)
     stacked = jax.jit(jax.vmap(lambda l: weights.layer_weights(
-        root, dims, l)))(jax.numpy.arange(3))
+        root, dims, l, qwen2.LAYER_LEAVES)))(jax.numpy.arange(3))
     for li in range(3):
-        one = weights.layer_weights(root, dims, li)
+        one = weights.layer_weights(root, dims, li, qwen2.LAYER_LEAVES)
         for k in one:
             np.testing.assert_allclose(np.asarray(stacked[k][li]),
                                        np.asarray(one[k]), rtol=1e-6,
@@ -201,8 +228,9 @@ def test_reference_gaps_do_not_depend_on_the_blocking():
     served = [(rng.integers(0, 100, n).tolist(), rng.integers(0, 100, m)
                .tolist()) for n, m in ((5, 3), (40, 9), (300, 4), (2, 2))]
     kw = dict(eps=1e-6, theta=1e4, padded_vocab=128, tied=False)
-    one = reference.served_gaps(dims, 5, served, budget=1 << 14, **kw)
-    many = reference.served_gaps(dims, 5, served, budget=1, chunk=4, **kw)
+    one = reference.served_gaps(qwen2, dims, 5, served, budget=1 << 14, **kw)
+    many = reference.served_gaps(qwen2, dims, 5, served, budget=1, chunk=4,
+                                 **kw)
     assert [len(g) for g in one] == [3, 9, 4, 2]
     for a, b in zip(one, many):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-6)
@@ -211,10 +239,11 @@ def test_reference_gaps_do_not_depend_on_the_blocking():
     # it reads 0 again; a token outside the vocabulary reads inf
     p = served[1][0]
     row = np.concatenate(reference.served_gaps(
-        dims, 5, [(p, [t]) for t in range(100)], **kw))
+        qwen2, dims, 5, [(p, [t]) for t in range(100)], **kw))
     best = int(np.argmin(row))
     assert row[best] == 0.0 and np.sum(row == 0.0) == 1
-    got = reference.served_gaps(dims, 5, [(p, [best, 100])], **kw)[0]
+    got = reference.served_gaps(qwen2, dims, 5, [(p, [best, 100])],
+                                **kw)[0]
     assert got[0] == 0.0 and np.isinf(got[1])
 
 
