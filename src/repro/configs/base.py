@@ -31,6 +31,17 @@ class MoEConfig:
     num_shared_experts: int = 0
     router_jitter: float = 0.0
     aux_loss_weight: float = 0.01
+    # The experts this device holds: ``experts_held`` of them (0: all
+    # ``num_experts``) from index ``expert_first``.  The router still
+    # scores all ``num_experts``; the expert banks hold only this share,
+    # and the layer computes only its part of the result (an expert-
+    # parallel deployment sums the shares over its devices).
+    experts_held: int = 0
+    expert_first: int = 0
+
+    @property
+    def held(self) -> int:
+        return self.experts_held or self.num_experts
 
 
 @dataclass(frozen=True)
@@ -62,6 +73,8 @@ class ModelConfig:
     vocab_size: int = 32000
     head_dim: int = 0            # 0 -> d_model // n_heads
     qkv_bias: bool = False
+    # per-head RMSNorm of q and k over head_dim, before RoPE (Qwen3)
+    qk_norm: bool = False
     rope_theta: float = 1e6
     rms_eps: float = 1e-5
     tie_embeddings: bool = False
@@ -214,6 +227,8 @@ class ModelConfig:
             total += attn + dense_mlp
         total += self.padded_vocab * d * (1 if self.tie_embeddings else 2)
         total += n_layers * 2 * d + d  # norms
+        if self.qk_norm:
+            total += self.n_layers * 2 * hd
         return total
 
 

@@ -80,7 +80,7 @@ def attn_init(key, cfg: ModelConfig, dtype):
     d, hd = cfg.d_model, cfg.resolved_head_dim
     H, KV = cfg.n_heads, cfg.n_kv_heads
     ks = jax.random.split(key, 4)
-    return {
+    p = {
         "wq": layers.linear_init(ks[0], d, H * hd, bias=cfg.qkv_bias,
                                  dtype=dtype),
         "wk": layers.linear_init(ks[1], d, KV * hd, bias=cfg.qkv_bias,
@@ -89,6 +89,10 @@ def attn_init(key, cfg: ModelConfig, dtype):
                                  dtype=dtype),
         "wo": layers.linear_init(ks[3], H * hd, d, dtype=dtype),
     }
+    if cfg.qk_norm:
+        p["q_norm"] = layers.rmsnorm_init(hd, dtype)
+        p["k_norm"] = layers.rmsnorm_init(hd, dtype)
+    return p
 
 
 def _proj_qkv(p, x, kv_src, cfg, cd, norm_scale=None):
@@ -114,6 +118,9 @@ def _proj_qkv(p, x, kv_src, cfg, cd, norm_scale=None):
                       site="xattn.kv" if cross else "attn.wv",
                       backend=be, interpret=ip,
                       norm_scale=norm_scale).reshape(B, T, KV, hd)
+    if cfg.qk_norm:                        # per head, before RoPE
+        q = layers.rmsnorm(p["q_norm"], q, cfg.rms_eps)
+        k = layers.rmsnorm(p["k_norm"], k, cfg.rms_eps)
     return q, k, v
 
 
@@ -378,7 +385,7 @@ def sublayer_init(key, cfg: ModelConfig, pos: int):
         p["moe"] = moe_lib.moe_init(ks[3], d, m.expert_d_ff or cfg.d_ff,
                                     m.num_experts,
                                     num_shared=m.num_shared_experts,
-                                    dtype=dtype)
+                                    held=m.experts_held, dtype=dtype)
     return p
 
 
@@ -462,26 +469,33 @@ def sublayer_decode(p, cfg: ModelConfig, pos_idx: int, x, cache, pos, ctx):
     if kind["cross"]:
         h = layers.rmsnorm(p["lnx"], x, cfg.rms_eps)
         x = x + cross_attn_decode(p["xattn"], h, cfg, cache)
+    x, _ = _mlp_serve(p, cfg, kind, x)
+    return x, new_cache
+
+
+def _mlp_serve(p, cfg: ModelConfig, kind: dict, x):
+    """A serving sub-layer's MLP on the residual stream x (B,S,d).
+    Returns (x, routed): ``routed`` (B,S) counts each token's (token, held
+    expert) pairs in an expert layer, else None.  Expert layers route
+    dropless and per token (``moe.moe_serve``), so every serving path
+    gives a token the same output whatever else shares its dispatch."""
     if kind["mlp"] == "dense":
         # ln2 scale fuses into the dual-GEMM swiglu prologue; the
         # residual join fuses into the mlp.wo store
         h = layers.rmsnorm_normalize(x, cfg.rms_eps)
-        x = layers.swiglu(p["mlp"], h, _cdtype(cfg),
-                          backend=cfg.gemm_backend,
-                          interpret=cfg.pallas_interpret, residual=x,
-                          norm_scale=p["ln2"]["scale"])
-    elif kind["mlp"] == "moe":
+        return layers.swiglu(p["mlp"], h, _cdtype(cfg),
+                             backend=cfg.gemm_backend,
+                             interpret=cfg.pallas_interpret, residual=x,
+                             norm_scale=p["ln2"]["scale"]), None
+    if kind["mlp"] == "moe":
         h = layers.rmsnorm(p["ln2"], x, cfg.rms_eps)
-        m = cfg.moe
-        y, _ = moe_lib.moe_apply(p["moe"], h, top_k=m.top_k,
-                                 capacity_factor=max(m.capacity_factor, 2.0),
-                                 groups=1,  # decode: one global group
-                                 compute_dtype=_cdtype(cfg),
-                                 aux_loss_weight=0.0,
-                                 backend=cfg.gemm_backend,
-                                 interpret=cfg.pallas_interpret)
-        x = x + y
-    return x, new_cache
+        y, routed = moe_lib.moe_serve(p["moe"], h, top_k=cfg.moe.top_k,
+                                      first=cfg.moe.expert_first,
+                                      compute_dtype=_cdtype(cfg),
+                                      backend=cfg.gemm_backend,
+                                      interpret=cfg.pallas_interpret)
+        return x + y, routed
+    return x, None
 
 
 def sublayer_prefill(p, cfg: ModelConfig, pos_idx: int, x, cache, pos,
@@ -493,22 +507,14 @@ def sublayer_prefill(p, cfg: ModelConfig, pos_idx: int, x, cache, pos,
     ``sublayer_decode``.
     """
     kind = sublayer_kind(cfg, pos_idx)
-    assert kind["mixer"] == "attn" and not kind["cross"] \
-        and kind["mlp"] != "moe", "use supports_batched_prefill() to gate"
+    assert kind["mixer"] == "attn" and not kind["cross"], \
+        "use supports_batched_prefill() to gate"
     new_cache = dict(cache)
     h = layers.rmsnorm_normalize(x, cfg.rms_eps)
     out, kv = attn_prefill(p["attn"], h, cfg, cache, pos, lengths,
                            norm_scale=p["ln1"]["scale"])
     new_cache.update(kv)
-    x = x + out
-    if kind["mlp"] == "dense":
-        # ln2 scale fuses into the dual-GEMM swiglu prologue; the
-        # residual join fuses into the mlp.wo store
-        h = layers.rmsnorm_normalize(x, cfg.rms_eps)
-        x = layers.swiglu(p["mlp"], h, _cdtype(cfg),
-                          backend=cfg.gemm_backend,
-                          interpret=cfg.pallas_interpret, residual=x,
-                          norm_scale=p["ln2"]["scale"])
+    x, _ = _mlp_serve(p, cfg, kind, x + out)
     return x, new_cache
 
 
@@ -696,17 +702,24 @@ def _decode_step(cfg: ModelConfig, params, cache, token, pos, ctx=None):
 def supports_batched_prefill(cfg: ModelConfig) -> bool:
     """True when ``prefill_step`` reproduces the decode path bit-for-bit.
 
-    Requires every sub-layer to be a plain causal-attention + dense-MLP
-    block with a linear (non-ring) KV cache: mamba state recurrences,
-    cross-attention contexts, MoE capacity routing (whose token dropping
-    depends on how many tokens share a dispatch) and sliding-window ring
-    buffers all break per-row equivalence with single-token decoding.
+    Requires every sub-layer to be a causal-attention block with a linear
+    (non-ring) KV cache and a dense or expert MLP: mamba state recurrences,
+    cross-attention contexts and sliding-window ring buffers break per-row
+    equivalence with single-token decoding.  Expert layers qualify because
+    serving routes them dropless and per token (``moe.moe_serve``): a
+    token's output does not depend on what else shares its dispatch.
     """
     if cfg.sliding_window or cfg.family in ("vlm", "audio"):
         return False
     return all(
-        k["mixer"] == "attn" and not k["cross"] and k["mlp"] != "moe"
+        k["mixer"] == "attn" and not k["cross"]
         for k in (sublayer_kind(cfg, i) for i in range(period(cfg))))
+
+
+def routes_experts(cfg: ModelConfig) -> bool:
+    """True when some sub-layer is an expert layer."""
+    return any(sublayer_kind(cfg, i)["mlp"] == "moe"
+               for i in range(period(cfg)))
 
 
 def prefill_step(cfg: ModelConfig, params, cache, tokens, pos, lengths):
@@ -756,11 +769,12 @@ def _prefill_step(cfg: ModelConfig, params, cache, tokens, pos, lengths):
 def supports_pipeline(cfg: ModelConfig) -> bool:
     """True when the pp step functions reproduce the dense path bit for
     bit: plain causal-attention + dense-MLP stack (the batched-prefill
-    gate) whose ``n_super`` super-blocks split evenly over the stages."""
+    gate, expert layers not staged) whose ``n_super`` super-blocks split
+    evenly over the stages."""
     pp = cfg.pp_stages
     return (pp > 1 and len(cfg.mesh_shape) == 3
             and cfg.mesh_shape[0] == pp and n_super(cfg) % pp == 0
-            and supports_batched_prefill(cfg))
+            and supports_batched_prefill(cfg) and not routes_experts(cfg))
 
 
 def _check_pp(cfg: ModelConfig):
@@ -859,56 +873,41 @@ def prefill_step_pp(cfg: ModelConfig, params, cache, tokens, pos, lengths):
 
 def supports_paged_kv(cfg: ModelConfig) -> bool:
     """True when the paged serving path reproduces dense decoding bit for
-    bit: same gate as :func:`supports_batched_prefill` (pure causal attn +
-    dense MLP, linear cache) — mamba state, MoE routing, cross-attention
-    and sliding-window rings have no page-gather equivalence."""
+    bit: same gate as :func:`supports_batched_prefill` (causal attn + a
+    dense or per-token expert MLP, linear cache) — mamba state,
+    cross-attention and sliding-window rings have no page-gather
+    equivalence."""
     return supports_batched_prefill(cfg)
 
 
 def _sublayer_decode_paged(p, cfg, pos_idx, x, cache, pos, bt):
     kind = sublayer_kind(cfg, pos_idx)
-    assert kind["mixer"] == "attn" and not kind["cross"] \
-        and kind["mlp"] != "moe", "use supports_paged_kv() to gate"
+    assert kind["mixer"] == "attn" and not kind["cross"], \
+        "use supports_paged_kv() to gate"
     h = layers.rmsnorm_normalize(x, cfg.rms_eps)
     out, new_cache = attn_decode_paged(p["attn"], h, cfg, cache, pos, bt,
                                        norm_scale=p["ln1"]["scale"])
-    x = x + out
-    if kind["mlp"] == "dense":
-        # ln2 scale fuses into the dual-GEMM swiglu prologue; the
-        # residual join fuses into the mlp.wo store
-        h = layers.rmsnorm_normalize(x, cfg.rms_eps)
-        x = layers.swiglu(p["mlp"], h, _cdtype(cfg),
-                          backend=cfg.gemm_backend,
-                          interpret=cfg.pallas_interpret, residual=x,
-                          norm_scale=p["ln2"]["scale"])
-    return x, new_cache
+    return (*_mlp_serve(p, cfg, kind, x + out), new_cache)
 
 
 def _sublayer_prefill_paged(p, cfg, pos_idx, x, cache, pos, lengths, bt):
     kind = sublayer_kind(cfg, pos_idx)
-    assert kind["mixer"] == "attn" and not kind["cross"] \
-        and kind["mlp"] != "moe", "use supports_paged_kv() to gate"
+    assert kind["mixer"] == "attn" and not kind["cross"], \
+        "use supports_paged_kv() to gate"
     h = layers.rmsnorm_normalize(x, cfg.rms_eps)
     out, new_cache = attn_prefill_paged(p["attn"], h, cfg, cache, pos,
                                         lengths, bt,
                                         norm_scale=p["ln1"]["scale"])
-    x = x + out
-    if kind["mlp"] == "dense":
-        # ln2 scale fuses into the dual-GEMM swiglu prologue; the
-        # residual join fuses into the mlp.wo store
-        h = layers.rmsnorm_normalize(x, cfg.rms_eps)
-        x = layers.swiglu(p["mlp"], h, _cdtype(cfg),
-                          backend=cfg.gemm_backend,
-                          interpret=cfg.pallas_interpret, residual=x,
-                          norm_scale=p["ln2"]["scale"])
-    return x, new_cache
+    return (*_mlp_serve(p, cfg, kind, x + out), new_cache)
 
 
 def decode_step_paged(cfg: ModelConfig, params, cache, token, pos,
                       block_tables):
     """Paged twin of :func:`decode_step`: token (B,), pos (B,),
     block_tables (B, n_pg) int32.  cache is :func:`init_paged_cache`'s
-    pytree.  Returns (logits (B,V), new_cache)."""
+    pytree.  Returns (logits (B,V), new_cache); a model with expert layers
+    (:func:`routes_experts`) adds ``routed`` (B,) int32, each row's
+    (token, held expert) pairs summed over the layers."""
     substrate.check_backend(cfg.gemm_backend)
     with sharding.gemm_mesh_scope(cfg):
         return _paged_step(cfg, params, cache, token[:, None], pos,
@@ -919,7 +918,9 @@ def prefill_step_paged(cfg: ModelConfig, params, cache, tokens, pos,
                        lengths, block_tables):
     """Paged twin of :func:`prefill_step`: tokens (B,C) right-padded,
     pos/lengths (B,), block_tables (B, n_pg).  Returns (logits at each
-    row's last valid chunk token, new_cache)."""
+    row's last valid chunk token, new_cache), and for a model with expert
+    layers ``routed`` (B,): each row's (token, held expert) pairs over its
+    valid chunk tokens, summed over the layers."""
     substrate.check_backend(cfg.gemm_backend)
     with sharding.gemm_mesh_scope(cfg):
         return _paged_step(cfg, params, cache, tokens, pos, lengths,
@@ -935,25 +936,35 @@ def _paged_step(cfg, params, cache, tokens, pos, lengths, bt):
 
     def body(x, xs):
         p_block, cache_block = xs
-        new_caches = []
+        new_caches, routed = [], None
         for i in range(P):
             if lengths is None:
-                x, nc = _sublayer_decode_paged(p_block[i], cfg, i, x,
-                                               cache_block[i], pos, bt)
+                x, r, nc = _sublayer_decode_paged(p_block[i], cfg, i, x,
+                                                  cache_block[i], pos, bt)
             else:
-                x, nc = _sublayer_prefill_paged(p_block[i], cfg, i, x,
-                                                cache_block[i], pos,
-                                                lengths, bt)
+                x, r, nc = _sublayer_prefill_paged(p_block[i], cfg, i, x,
+                                                   cache_block[i], pos,
+                                                   lengths, bt)
             new_caches.append(nc)
-        return x, tuple(new_caches)
+            if r is not None:
+                routed = r if routed is None else routed + r
+        return x, (tuple(new_caches), routed)
 
-    x, new_cache = jax.lax.scan(body, x, (params["blocks"], cache))
+    x, (new_cache, routed) = jax.lax.scan(body, x, (params["blocks"], cache))
     x = layers.rmsnorm(params["final_norm"], x, cfg.rms_eps)
     if lengths is not None:
-        last = jnp.clip(jnp.asarray(lengths, jnp.int32) - 1, 0, C - 1)
+        lengths = jnp.asarray(lengths, jnp.int32)
+        last = jnp.clip(lengths - 1, 0, C - 1)
         x = jnp.take_along_axis(x, last[:, None, None], axis=1)  # (B,1,d)
     logits = _logits(cfg, params, x, cd)
-    return constrain(logits, "logits")[:, 0], new_cache
+    out = (constrain(logits, "logits")[:, 0], new_cache)
+    if routed is None:
+        return out
+    routed = jnp.sum(routed, axis=0)                             # (B,C)
+    if lengths is not None:                      # valid chunk tokens only
+        routed = jnp.where(jnp.arange(C)[None, :] < lengths[:, None],
+                           routed, 0)
+    return (*out, jnp.sum(routed, axis=1))
 
 
 # ---------------------------------------------------------------------------

@@ -14,6 +14,10 @@ shard-local).  Within a group:
   5. results gather back to token order and combine with router weights.
 
 Memory is O(T*k*d); assignments beyond capacity are dropped (cf=1.25 train).
+
+Serving routes with :func:`moe_serve` instead: the same router, no
+capacity, so a token's output never depends on the other rows of its
+dispatch, and only the experts this device holds are computed.
 """
 from __future__ import annotations
 
@@ -26,24 +30,90 @@ from repro.parallel import sharding
 from repro.parallel.sharding import constrain
 
 
-def moe_init(key, d_model, d_ff, num_experts, *, num_shared=0,
+def moe_init(key, d_model, d_ff, num_experts, *, num_shared=0, held=0,
              dtype=jnp.float32):
+    """The router scores all ``num_experts``; the banks hold ``held`` of
+    them (0: all)."""
     ks = jax.random.split(key, 5)
     scale = 1.0 / jnp.sqrt(d_model)
+    n = held or num_experts
     p = {
         "router": layers.normal_init(ks[0], (d_model, num_experts), 0.02,
                                      jnp.float32),
-        "wi_gate": layers.normal_init(ks[1], (num_experts, d_model, d_ff),
-                                      scale, dtype),
-        "wi_up": layers.normal_init(ks[2], (num_experts, d_model, d_ff),
-                                    scale, dtype),
-        "wo": layers.normal_init(ks[3], (num_experts, d_ff, d_model),
+        "wi_gate": layers.normal_init(ks[1], (n, d_model, d_ff), scale,
+                                      dtype),
+        "wi_up": layers.normal_init(ks[2], (n, d_model, d_ff), scale,
+                                    dtype),
+        "wo": layers.normal_init(ks[3], (n, d_ff, d_model),
                                  1.0 / jnp.sqrt(d_ff), dtype),
     }
     if num_shared:
         p["shared"] = layers.swiglu_init(ks[4], d_model, d_ff * num_shared,
                                          dtype)
     return p
+
+
+def _route(p, xf, top_k, backend, interpret):
+    """float32 router over all E experts: softmax, top-k, the top-k
+    probabilities renormalised to sum to 1.  xf: (..., T, d)."""
+    d, E = p["router"].shape
+    logits = substrate.gemm(xf.astype(jnp.float32), p["router"],
+                            site="moe.router", backend=backend,
+                            interpret=interpret,
+                            shard=sharding.gemm_shard_ctx(
+                                "moe.router", xf.size // d, d, E))
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_vals, top_idx = jax.lax.top_k(probs, top_k)
+    top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+    return probs, top_vals, top_idx
+
+
+def moe_serve(p, x, *, top_k, first=0, compute_dtype=jnp.bfloat16,
+              backend="xla", interpret=None):
+    """Serving MoE over the held experts: x (B, S, d) -> (y, routed).
+
+    The banks hold E_h experts, global indices ``first`` .. ``first+E_h-1``;
+    the router scores all E.  ``y`` is the held experts' part of the
+    layer's output (an expert-parallel deployment sums the parts over its
+    devices); ``routed`` (B, S) int32 counts each token's (token, held
+    expert) pairs.
+
+    Dropless and per token: a token picks an expert at most once, so each
+    held expert gets one row per token of the dispatch (capacity = rows)
+    and nothing is dropped; a token's output depends on its own row alone.
+    The expert GEMMs run over all E_h x B*S rows; a row whose token did not
+    pick the expert is weighted 0 at the combine.
+    """
+    B, S, d = x.shape
+    T = B * S
+    Eh = p["wi_gate"].shape[0]
+    xf = x.reshape(T, d)
+    _, top_vals, top_idx = _route(p, xf, top_k, backend, interpret)
+    with jax.named_scope("moe.dispatch"):
+        picked = top_idx[:, :, None] == first + jnp.arange(Eh)  # (T,k,Eh)
+        # each held expert's combine weight per token: its renormalised
+        # probability where the token picked it, else 0
+        w = jnp.sum(jnp.where(picked, top_vals[:, :, None], 0.0), axis=1)
+        routed = jnp.sum(picked, axis=(1, 2), dtype=jnp.int32)
+        he = jnp.broadcast_to(xf.astype(compute_dtype), (1, Eh, T, d))
+    esh = sharding.expert_shard_ctx(Eh)
+    hg = substrate.expert_gemm(he, p["wi_gate"].astype(compute_dtype),
+                               site="moe.wi_gate", backend=backend,
+                               shard=esh, interpret=interpret)
+    hu = substrate.expert_gemm(he, p["wi_up"].astype(compute_dtype),
+                               site="moe.wi_up", backend=backend,
+                               shard=esh, interpret=interpret)
+    hout = substrate.expert_gemm(jax.nn.silu(hg) * hu,
+                                 p["wo"].astype(compute_dtype),
+                                 site="moe.wo", backend=backend, shard=esh,
+                                 interpret=interpret)      # (1,Eh,T,d)
+    with jax.named_scope("moe.combine"):
+        y = jnp.sum(hout[0].astype(jnp.float32) * w.T[:, :, None], axis=0)
+    y = y.reshape(B, S, d)
+    if "shared" in p:
+        y = y + layers.swiglu(p["shared"], x, compute_dtype,
+                              backend=backend, interpret=interpret)
+    return y.astype(x.dtype), routed.reshape(B, S)
 
 
 def moe_apply(p, x, *, top_k, capacity_factor=1.25, groups=0,
@@ -55,17 +125,13 @@ def moe_apply(p, x, *, top_k, capacity_factor=1.25, groups=0,
     G = groups or B
     Tg = T // G
     E = p["router"].shape[1]
+    if p["wi_gate"].shape[0] != E:
+        raise ValueError("capacity routing computes every expert; a layer "
+                         "that holds a share of them serves (moe_serve)")
     TK = Tg * top_k
     xf = x.reshape(G, Tg, d)
 
-    logits = substrate.gemm(xf.astype(jnp.float32), p["router"],
-                            site="moe.router", backend=backend,
-                            interpret=interpret,
-                            shard=sharding.gemm_shard_ctx(
-                                "moe.router", G * Tg, d, E))
-    probs = jax.nn.softmax(logits, axis=-1)                  # (G,Tg,E)
-    top_vals, top_idx = jax.lax.top_k(probs, top_k)          # (G,Tg,k)
-    top_vals = top_vals / jnp.sum(top_vals, axis=-1, keepdims=True)
+    probs, top_vals, top_idx = _route(p, xf, top_k, backend, interpret)
 
     flat_e = top_idx.reshape(G, TK)
     onehot = jax.nn.one_hot(flat_e, E, dtype=jnp.float32)    # (G,TK,E)

@@ -315,7 +315,7 @@ class ServingEngine:
         if mode == "batched" and not lm.supports_batched_prefill(cfg):
             raise ValueError(
                 f"{cfg.name}: model family does not support batched "
-                f"prefill (mamba/MoE/cross-attn/sliding-window state); "
+                f"prefill (mamba/cross-attn/sliding-window state); "
                 f"use prefill_mode='token' or 'auto'")
         if mode not in ("batched", "token"):
             raise ValueError(f"unknown prefill_mode {mode!r}")
@@ -396,6 +396,11 @@ class ServingEngine:
         self.restored_requests: List[Request] = []  # set by restore()
 
         self._prefill_launches = 0   # per-trace GEMM launches of one chunk
+        # expert-kernel rows per dispatched token: every held expert of
+        # every expert layer computes one row per token (moe_serve)
+        self._expert_rows = (cfg.moe.held * sum(
+            cfg.is_moe_layer(i) for i in range(cfg.n_layers))
+            if lm.routes_experts(cfg) else 0)
         self._step_s = 0.0           # the latest engine.step span
         # Host-clock counters are summed from the engine.* spans: a
         # dispatch's *_time_s is its launch + wait spans (+ sample for
@@ -410,6 +415,10 @@ class ServingEngine:
                           prefill_gemm_dispatches=0,
                           pages_used_peak=0, concurrency_peak=0,
                           prefix_hit_tokens=0,
+                          # expert layers (paged steps): (token, held
+                          # expert) pairs of live tokens, and the rows the
+                          # expert kernels computed, padding included
+                          moe_assignments=0, moe_expert_rows=0,
                           # resilience counters (flat ints: benches reset
                           # stats wholesale by scalar type)
                           sample_retries=0, kernel_fault_retries=0,
@@ -582,9 +591,11 @@ class ServingEngine:
         trace/launch boundary, and on non-finite logits in the active
         ``rows`` (the ``engine.sample`` corruption point — also catches a
         *real* kernel producing NaN/Inf).  Returns ``(logits, new_cache,
-        bad_rows)``; ``bad_rows`` non-empty means the retry budget is
-        spent and the caller must fail those rows' requests instead of
-        sampling garbage.  A persistent KernelFault re-raises.
+        bad_rows, extra)``, ``extra`` being what the step returns beyond
+        those two (a paged step's ``routed``); ``bad_rows`` non-empty means
+        the retry budget is spent and the caller must fail those rows'
+        requests instead of sampling garbage.  A persistent KernelFault
+        re-raises.
 
         Each launch runs in the ``engine.<phase>.launch`` span; the
         finiteness readback, the first sync after a decode launch, in
@@ -599,14 +610,14 @@ class ServingEngine:
         for attempt in range(retries + 1):
             try:
                 with self._span(f"engine.{phase}.launch", total):
-                    logits, new_cache = dispatch()
+                    logits, new_cache, *extra = dispatch()
             except KernelFault:
                 if attempt < retries:
                     self.stats["kernel_fault_retries"] += 1
                     continue
                 raise
             if logits is None:           # prefill: nothing to sample
-                return None, new_cache, ()
+                return None, new_cache, (), extra
             with self._span(f"engine.{phase}.wait", f"{phase}_wait_s",
                             total):
                 if self._chaos is not None and self._chaos.fire(
@@ -621,7 +632,7 @@ class ServingEngine:
             if bad and attempt < retries:
                 self.stats["sample_retries"] += 1
                 continue
-            return logits, new_cache, bad
+            return logits, new_cache, bad, extra
         raise AssertionError("unreachable")
 
     # ------------------------------------------------------------ prefill
@@ -638,16 +649,26 @@ class ServingEngine:
         if self.paged:
             self._publish_prefix(s)
 
+    def _count_routed(self, extra, n_rows: int, rows=slice(None)):
+        """Add a dispatch's expert-layer counters, if its step returned
+        them: ``extra`` holds the paged step's per-row ``routed``, read
+        after the dispatch's own sync; ``n_rows`` is the token rows
+        dispatched and ``rows`` the live ones."""
+        if extra:
+            self.stats["moe_expert_rows"] += self._expert_rows * n_rows
+            self.stats["moe_assignments"] += int(
+                np.asarray(extra[0])[rows].sum())
+
     def _prefill_dispatch(self, step, seqs, n_tokens: int, *args) -> bool:
         """One prefill dispatch of ``step`` over the prefill cache, then
         the existing ``block_until_ready`` in the ``engine.prefill.wait``
         span.  A persistent KernelFault fails and releases ``seqs`` and
         returns False."""
         try:
-            _, cache, _ = self._guarded_dispatch(
+            _, cache, _, extra = self._guarded_dispatch(
                 "prefill",
-                lambda: (None, step(self.params, self._prefill_cache(),
-                                    *map(jnp.asarray, args))[1]),
+                lambda: (None, *step(self.params, self._prefill_cache(),
+                                     *map(jnp.asarray, args))[1:]),
                 rows=())
         except KernelFault as exc:
             for s in seqs:
@@ -661,6 +682,7 @@ class ServingEngine:
             jax.block_until_ready(cache)
         self.stats["prefill_dispatches"] += 1
         self.stats["prefill_tokens"] += n_tokens
+        self._count_routed(extra, int(np.size(args[0])))
         return True
 
     def _prefill_tick(self):
@@ -816,7 +838,7 @@ class ServingEngine:
         tokens (``engine.commit``).  A persistent KernelFault fails and
         releases every row's sequence."""
         try:
-            logits, new_cache, bad = self._guarded_dispatch(
+            logits, new_cache, bad, extra = self._guarded_dispatch(
                 "decode",
                 lambda: step(self.params, self.cache,
                              *map(jnp.asarray, args)),
@@ -831,6 +853,7 @@ class ServingEngine:
             nxt = self._sample(logits, jnp.asarray(temps))
         self.stats["decode_dispatches"] += 1
         self.stats["decode_tokens"] += len(rows)
+        self._count_routed(extra, len(temps), [r for r, _ in rows])
         with self._span("engine.commit"):
             now = self.clock()
             for r, s in rows:

@@ -104,8 +104,8 @@ def test_dense_disagg_stream_identical(backend):
 
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_moe_disagg_stream_identical(backend):
-    """MoE family (token prefill — the batched path doesn't route
-    experts), 2+2 pods, pp=1."""
+    """MoE family through token-by-token prefill (the path every family
+    has; MoE also serves batched and paged), 2+2 pods, pp=1."""
     arch = "qwen3-moe-30b-a3b"
     colo, _ = _run(ServingEngine, ServeConfig(**_KW, prefill_mode="token"),
                    arch=arch, backend=backend)

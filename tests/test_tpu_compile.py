@@ -16,7 +16,11 @@ widths of the benchmark models:
   instructions must keep the names the chip benchmark reads and carry
   their substrate site labels as named scopes, and which, at the 0.5b
   offline cell's shape, writes its new K/V rows with no element-wise
-  gather of the written pages.
+  gather of the written pages;
+* the qwen3-moe-30b-a3b paged decode and prefill steps at the widths and
+  expert share of its benchmark cell (16 of 128 experts held, B 64, chunk
+  64), two layers deep, with one kernel per site per layer under the
+  names and scopes the chip benchmark reads.
 
 Each compiled program must hold the Pallas kernel (``tpu_custom_call``)
 and fit the chip's 16 GB of HBM.  Kernels pass ``interpret=False``
@@ -272,3 +276,50 @@ def test_qwen2_paged_decode_writes_back_rows_not_gathered_pages(one_chip):
     assert len(counts) >= 6, counts
     per_page = B * page * cfg.n_kv_heads * cfg.resolved_head_dim
     assert max(counts) < per_page, (counts, per_page)
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+def test_qwen3_moe_paged_steps_compile_with_one_kernel_per_site(one_chip,
+                                                                phase):
+    """The engine's paged steps for the qwen3-moe cell's configuration
+    (held share 16 of 128, q/k norm) at B 64, chunk 64, max_seq 512, two
+    of its layers (the scan body is one layer): each site's kernel reads as
+    a chip-benchmark kernel event and carries its site's scope, once."""
+    import re
+    import sys
+    from pathlib import Path
+    sys.path.insert(0, str(Path(__file__).resolve().parents[1]
+                           / "benchmarks" / "chip"))
+    from chipbench import harness, xtrace
+    from repro.serving.engine import jit_step
+
+    base = get_config("qwen3-moe-30b-a3b")
+    cfg = dataclasses.replace(
+        base, n_layers=2, vocab_size=18992, gemm_backend="arrayflex",
+        pallas_interpret=False, moe=dataclasses.replace(
+            base.moe, experts_held=16))
+    params = _shapes(one_chip, jax.eval_shape(
+        lambda: lm.init_params(cfg, jax.random.PRNGKey(0))))
+    cache = _shapes(one_chip, jax.eval_shape(
+        lambda: lm.init_paged_cache(cfg, 2049, 16)))
+    B, C, bt = 64, 64, _i32(one_chip, 64, 512 // 16)
+    if phase == "decode":
+        fn, args = lm.decode_step_paged, (_i32(one_chip, B),
+                                          _i32(one_chip, B), bt)
+    else:
+        fn, args = lm.prefill_step_paged, (_i32(one_chip, B, C),
+                                           _i32(one_chip, B),
+                                           _i32(one_chip, B), bt)
+    compiled = _compile_and_check(jit_step(fn, cfg),
+                                  (params, cache, *args))
+    kernels = [ln.strip().removeprefix("ROOT ")
+               for ln in compiled.as_text().splitlines()
+               if 'custom_call_target="tpu_custom_call"' in ln]
+    event = re.compile(harness.KERNEL_EVENT)
+    assert all(event.search(xtrace.op_name(k)) for k in kernels)
+    scopes = sorted(m.group(1) for k in kernels
+                    for m in [re.search(r'op_name="[^"]*/([^/"]+)/jit\('
+                                        r'_(?:expert_)?gemm\)/', k)] if m)
+    assert scopes == sorted(
+        ["attn.wq", "attn.wk", "attn.wv", "attn.qk", "attn.pv", "attn.wo",
+         "moe.router", "moe.wi_gate", "moe.wi_up", "moe.wo", "unembed"])
