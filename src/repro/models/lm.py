@@ -240,21 +240,21 @@ def attn_prefill(p, x, cfg: ModelConfig, cache, pos, lengths,
     return out, {"k": k_cache, "v": v_cache}
 
 
-def attn_decode_paged(p, x, cfg: ModelConfig, cache, pos, block_tables,
-                      norm_scale=None):
+def attn_decode_paged(p, x, cfg: ModelConfig, cache, layer, pos,
+                      block_tables, norm_scale=None):
     """Single-token attention against the paged K/V pool.
 
-    cache: {'kp','vp'} physical pools (n_pages, page, KV, hd);
-    block_tables: (B, n_pg) int32 physical page ids per sequence.  The
-    pool is gathered into the (B, L = n_pg*page, KV, hd) logical view —
-    L equals the dense cache length by the engine's page|max_seq
-    contract — then the write, mask, softmax and QK/PV dispatches are
-    *identical* to the dense ``attn_decode`` vector-pos path, which is
-    what keeps paged and dense greedy streams bit-identical.  Only the
-    new token row scatters back, to row ``slot % page`` of its physical
-    page: the engine's sharing invariant puts every write position in a
-    uniquely-owned page (aliased scratch rows collide on page 0, which
-    live rows never attend).
+    cache: {'kp','vp'} stacked physical pools (n_layers, n_pages, page,
+    KV*hd) (:func:`init_paged_cache`), read at ``layer``; block_tables:
+    (B, n_pg) int32 physical page ids per sequence.  The pool is gathered
+    into the (B, L = n_pg*page, KV, hd) logical view — L equals the dense
+    cache length by the engine's page|max_seq contract — then the write,
+    mask, softmax and QK/PV dispatches are *identical* to the dense
+    ``attn_decode`` vector-pos path, which is what keeps paged and dense
+    greedy streams bit-identical.  The pool itself is not written: returns
+    the attention output and the token's new rows ``{'kp','vp'}`` (B,
+    KV*hd), which :func:`_paged_step` writes to row ``slot % page`` of
+    each row's physical page after the layer scan.
     """
     cd = _cdtype(cfg)
     q, k_new, v_new = _proj_qkv(p, x, None, cfg, cd, norm_scale)
@@ -263,39 +263,36 @@ def attn_decode_paged(p, x, cfg: ModelConfig, cache, pos, block_tables,
     positions = jnp.broadcast_to(pos, (B,))[:, None]
     q = layers.apply_rope(q, positions, cfg.rope_theta)
     k_new = layers.apply_rope(k_new, positions, cfg.rope_theta)
+    k_new = k_new.astype(cache["kp"].dtype)
+    v_new = v_new.astype(cache["vp"].dtype)
     bt = jnp.asarray(block_tables, jnp.int32)
-    n_pg, page = bt.shape[1], cache["kp"].shape[1]
-    k_view = attn_lib.gather_pages(cache["kp"], bt)
-    v_view = attn_lib.gather_pages(cache["vp"], bt)
-    L = n_pg * page
+    KV = cfg.n_kv_heads
+    k_view = attn_lib.gather_pages(cache["kp"], layer, bt, KV)
+    v_view = attn_lib.gather_pages(cache["vp"], layer, bt, KV)
+    L = k_view.shape[1]
     slot = jnp.minimum(jnp.broadcast_to(pos, (B,)), L - 1)
     hit = (jnp.arange(L)[None, :] == slot[:, None])[:, :, None, None]
-    k_view = jnp.where(hit, k_new.astype(cache["kp"].dtype), k_view)
-    v_view = jnp.where(hit, v_new.astype(cache["vp"].dtype), v_view)
+    k_view = jnp.where(hit, k_new, k_view)
+    v_view = jnp.where(hit, v_new, v_view)
     out = attn_lib.decode_attention(q, k_view, v_view, pos, window=0,
                                     backend=cfg.gemm_backend,
                                     interpret=cfg.pallas_interpret)
-    phys = jnp.take_along_axis(bt, (slot // page)[:, None], axis=1)[:, 0]
-    off = slot % page
-    kp = cache["kp"].at[phys, off].set(k_new[:, 0].astype(cache["kp"].dtype))
-    vp = cache["vp"].at[phys, off].set(v_new[:, 0].astype(cache["vp"].dtype))
     out = layers.linear(p["wo"], out.reshape(B, 1, -1), cd, site="attn.wo",
                         backend=cfg.gemm_backend,
                         interpret=cfg.pallas_interpret)
-    return out, {"kp": kp, "vp": vp}
+    return out, {"kp": k_new.reshape(B, -1), "vp": v_new.reshape(B, -1)}
 
 
-def attn_prefill_paged(p, x, cfg: ModelConfig, cache, pos, lengths,
+def attn_prefill_paged(p, x, cfg: ModelConfig, cache, layer, pos, lengths,
                        block_tables, norm_scale=None):
     """Chunked-prefill attention against the paged K/V pool.
 
     The logical view is gathered exactly as in :func:`attn_decode_paged`;
     the masked chunk scatter, causal mask and QK/PV dispatches then
-    mirror the dense ``attn_prefill`` step for step.  The whole view
-    scatters back (a chunk may span pages): rows alias only pages whose
-    gathered bytes they did not modify — shared prefix pages (writes
-    start at the page-aligned divergence point) and the scratch page —
-    so every duplicate scatter carries identical values.
+    mirror the dense ``attn_prefill`` step for step.  The pool is not
+    written: returns the attention output and the chunk's new rows
+    ``{'kp','vp'}`` (B, C, KV*hd), of which :func:`_paged_step` writes
+    positions ``pos + c`` for ``c < lengths`` after the layer scan.
     """
     cd = _cdtype(cfg)
     q, k_new, v_new = _proj_qkv(p, x, None, cfg, cd, norm_scale)
@@ -305,24 +302,22 @@ def attn_prefill_paged(p, x, cfg: ModelConfig, cache, pos, lengths,
     positions = pos[:, None] + jnp.arange(C)[None, :]          # (B,C)
     q = layers.apply_rope(q, positions, cfg.rope_theta)
     k_new = layers.apply_rope(k_new, positions, cfg.rope_theta)
+    k_new = k_new.astype(cache["kp"].dtype)
+    v_new = v_new.astype(cache["vp"].dtype)
     bt = jnp.asarray(block_tables, jnp.int32)
-    n_pg, page = bt.shape[1], cache["kp"].shape[1]
-    k_view = attn_lib.gather_pages(cache["kp"], bt)
-    v_view = attn_lib.gather_pages(cache["vp"], bt)
-    L = n_pg * page
+    KV = cfg.n_kv_heads
+    k_view = attn_lib.gather_pages(cache["kp"], layer, bt, KV)
+    v_view = attn_lib.gather_pages(cache["vp"], layer, bt, KV)
+    L = k_view.shape[1]
     j = jnp.arange(L)[None, :]                                 # (1,L)
     src = j - pos[:, None]                                     # (B,L)
     ok = (src >= 0) & (src < lengths[:, None])
     idx = jnp.clip(src, 0, C - 1)[:, :, None, None]
-    k_view = jnp.where(
-        ok[:, :, None, None],
-        jnp.take_along_axis(k_new.astype(cache["kp"].dtype), idx, axis=1),
-        k_view)
-    v_view = jnp.where(
-        ok[:, :, None, None],
-        jnp.take_along_axis(v_new.astype(cache["vp"].dtype), idx, axis=1),
-        v_view)
-    H, KV, hd = cfg.n_heads, cfg.n_kv_heads, cfg.resolved_head_dim
+    k_view = jnp.where(ok[:, :, None, None],
+                       jnp.take_along_axis(k_new, idx, axis=1), k_view)
+    v_view = jnp.where(ok[:, :, None, None],
+                       jnp.take_along_axis(v_new, idx, axis=1), v_view)
+    H, hd = cfg.n_heads, cfg.resolved_head_dim
     g = H // KV
     qg = q.reshape(B, C, KV, g, hd)
     scale = 1.0 / math.sqrt(hd)
@@ -334,12 +329,11 @@ def attn_prefill_paged(p, x, cfg: ModelConfig, cache, pos, lengths,
     out = attn_lib.pv_mix(w, v_view, backend=cfg.gemm_backend,
                           interpret=cfg.pallas_interpret)
     out = out.reshape(B, C, H, hd).astype(q.dtype)
-    kp = attn_lib.scatter_pages(cache["kp"], bt, k_view)
-    vp = attn_lib.scatter_pages(cache["vp"], bt, v_view)
     out = layers.linear(p["wo"], out.reshape(B, C, -1), cd, site="attn.wo",
                         backend=cfg.gemm_backend,
                         interpret=cfg.pallas_interpret)
-    return out, {"kp": kp, "vp": vp}
+    return out, {"kp": k_new.reshape(B, C, -1),
+                 "vp": v_new.reshape(B, C, -1)}
 
 
 def cross_attn_decode(p, x, cfg: ModelConfig, cache):
@@ -880,25 +874,25 @@ def supports_paged_kv(cfg: ModelConfig) -> bool:
     return supports_batched_prefill(cfg)
 
 
-def _sublayer_decode_paged(p, cfg, pos_idx, x, cache, pos, bt):
+def _sublayer_decode_paged(p, cfg, pos_idx, x, cache, layer, pos, bt):
     kind = sublayer_kind(cfg, pos_idx)
     assert kind["mixer"] == "attn" and not kind["cross"], \
         "use supports_paged_kv() to gate"
     h = layers.rmsnorm_normalize(x, cfg.rms_eps)
-    out, new_cache = attn_decode_paged(p["attn"], h, cfg, cache, pos, bt,
-                                       norm_scale=p["ln1"]["scale"])
-    return (*_mlp_serve(p, cfg, kind, x + out), new_cache)
+    out, rows = attn_decode_paged(p["attn"], h, cfg, cache, layer, pos, bt,
+                                  norm_scale=p["ln1"]["scale"])
+    return (*_mlp_serve(p, cfg, kind, x + out), rows)
 
 
-def _sublayer_prefill_paged(p, cfg, pos_idx, x, cache, pos, lengths, bt):
+def _sublayer_prefill_paged(p, cfg, pos_idx, x, cache, layer, pos, lengths,
+                            bt):
     kind = sublayer_kind(cfg, pos_idx)
     assert kind["mixer"] == "attn" and not kind["cross"], \
         "use supports_paged_kv() to gate"
     h = layers.rmsnorm_normalize(x, cfg.rms_eps)
-    out, new_cache = attn_prefill_paged(p["attn"], h, cfg, cache, pos,
-                                        lengths, bt,
-                                        norm_scale=p["ln1"]["scale"])
-    return (*_mlp_serve(p, cfg, kind, x + out), new_cache)
+    out, rows = attn_prefill_paged(p["attn"], h, cfg, cache, layer, pos,
+                                   lengths, bt, norm_scale=p["ln1"]["scale"])
+    return (*_mlp_serve(p, cfg, kind, x + out), rows)
 
 
 def decode_step_paged(cfg: ModelConfig, params, cache, token, pos,
@@ -928,29 +922,37 @@ def prefill_step_paged(cfg: ModelConfig, params, cache, tokens, pos,
 
 
 def _paged_step(cfg, params, cache, tokens, pos, lengths, bt):
+    """The paged steps' layer scan.  The scan runs over the parameters and
+    the layer index only: each layer gathers its pages from the stacked
+    pool where it lies, and returns its new K/V rows, not a pool.  After
+    the scan one scatter per pool leaf writes every layer's rows, so a
+    donated pool is updated in place (no pool-sized value is made)."""
     P = period(cfg)
     cd = _cdtype(cfg)
     tokens = jnp.asarray(tokens, jnp.int32)
+    bt = jnp.asarray(bt, jnp.int32)
     C = tokens.shape[1]
     x = layers.embed(params["embed"], tokens, cd)
 
     def body(x, xs):
-        p_block, cache_block = xs
-        new_caches, routed = [], None
+        p_block, layer = xs
+        rows, routed = [], None
         for i in range(P):
             if lengths is None:
-                x, r, nc = _sublayer_decode_paged(p_block[i], cfg, i, x,
-                                                  cache_block[i], pos, bt)
+                x, r, kv = _sublayer_decode_paged(p_block[i], cfg, i, x,
+                                                  cache[i], layer, pos, bt)
             else:
-                x, r, nc = _sublayer_prefill_paged(p_block[i], cfg, i, x,
-                                                   cache_block[i], pos,
+                x, r, kv = _sublayer_prefill_paged(p_block[i], cfg, i, x,
+                                                   cache[i], layer, pos,
                                                    lengths, bt)
-            new_caches.append(nc)
+            rows.append(kv)
             if r is not None:
                 routed = r if routed is None else routed + r
-        return x, (tuple(new_caches), routed)
+        return x, (tuple(rows), routed)
 
-    x, (new_cache, routed) = jax.lax.scan(body, x, (params["blocks"], cache))
+    layer_ids = jnp.arange(n_super(cfg), dtype=jnp.int32)
+    x, (rows, routed) = jax.lax.scan(body, x, (params["blocks"], layer_ids))
+    new_cache = _write_rows(cache, rows, pos, lengths, bt)
     x = layers.rmsnorm(params["final_norm"], x, cfg.rms_eps)
     if lengths is not None:
         lengths = jnp.asarray(lengths, jnp.int32)
@@ -965,6 +967,42 @@ def _paged_step(cfg, params, cache, tokens, pos, lengths, bt):
         routed = jnp.where(jnp.arange(C)[None, :] < lengths[:, None],
                            routed, 0)
     return (*out, jnp.sum(routed, axis=1))
+
+
+def _write_rows(cache, rows, pos, lengths, bt):
+    """Write the layer scan's new K/V rows into the stacked pools: one
+    scatter per pool leaf over (layer, physical page, row in page).
+
+    Decode (``lengths`` None): rows (NS, B, KV*hd) go to row ``slot %
+    page`` of page ``bt[b, slot // page]``, ``slot = min(pos, L - 1)`` as
+    in the attention's view; an idle row (pos 0, table of zeros) writes
+    the scratch page 0, which no live row attends.  Prefill: rows (NS, B,
+    C, KV*hd) go to positions ``pos + c`` for ``c < lengths[b]``; padded
+    chunk positions are dropped (an out-of-range page).  The engine's
+    sharing invariant puts every write in a uniquely-owned page: shared
+    prefix pages are never written, since writes start at the page-aligned
+    divergence point."""
+    n_pages, page = cache[0]["kp"].shape[1:3]
+    B, n_pg = bt.shape
+    L = n_pg * page
+    pos = jnp.broadcast_to(jnp.asarray(pos, jnp.int32), (B,))
+    if lengths is None:
+        slot = jnp.minimum(pos, L - 1)                              # (B,)
+        phys = bt[jnp.arange(B), slot // page]
+    else:
+        j = jnp.arange(rows[0]["kp"].shape[2])[None, :]
+        slot = pos[:, None] + j                                     # (B,C)
+        keep = (j < jnp.asarray(lengths, jnp.int32)[:, None]) & (slot < L)
+        phys = jnp.take_along_axis(bt, jnp.minimum(slot // page, n_pg - 1),
+                                   axis=1)
+        phys = jnp.where(keep, phys, n_pages)
+    off = slot % page
+    layer = jnp.arange(cache[0]["kp"].shape[0]).reshape(
+        (-1,) + (1,) * phys.ndim)
+    return tuple(
+        {name: pool.at[layer, phys, off].set(new[name], mode="drop")
+         for name, pool in pools.items()}
+        for pools, new in zip(cache, rows))
 
 
 # ---------------------------------------------------------------------------
@@ -1066,10 +1104,14 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
                      dtype=jnp.bfloat16):
     """Zero-initialized paged K/V pools for ``decode_step_paged`` /
     ``prefill_step_paged``: per layer ``{'kp','vp'}`` of shape
-    ``(NS, n_pages, page_size, KV, hd)``.  Unlike :func:`init_cache`
+    ``(NS, n_pages, page_size, KV * hd)``.  Unlike :func:`init_cache`
     there is no batch dimension — residency is the engine's block tables,
     so K/V memory scales with the page budget, not ``max_batch * max_seq``
-    (page 0 is the engine's scratch page)."""
+    (page 0 is the engine's scratch page).  A token's heads lie side by
+    side on one row, so a page is whole (page, KV*hd) tiles on a TPU and
+    the device keeps the pool in its natural layout: with (KV, hd) as the
+    minor axes a narrow head (qwen2-0.5b: 2 x 64) would make the device
+    store pages along the lanes and re-lay the pool out on every step."""
     if not supports_paged_kv(cfg):
         raise ValueError(f"{cfg.name}: family does not support the paged "
                          f"KV path (see supports_paged_kv)")
@@ -1077,6 +1119,6 @@ def init_paged_cache(cfg: ModelConfig, n_pages: int, page_size: int,
     NS = n_super(cfg)
     hd, KV = cfg.resolved_head_dim, cfg.n_kv_heads
     return tuple(
-        {"kp": jnp.zeros((NS, n_pages, page_size, KV, hd), dtype),
-         "vp": jnp.zeros((NS, n_pages, page_size, KV, hd), dtype)}
+        {"kp": jnp.zeros((NS, n_pages, page_size, KV * hd), dtype),
+         "vp": jnp.zeros((NS, n_pages, page_size, KV * hd), dtype)}
         for _ in range(P))

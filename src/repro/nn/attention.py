@@ -210,29 +210,24 @@ def attention(q, k, v, *, causal=True, window=0, q_offset=0,
                              kv_chunk=kv_chunk)
 
 
-def gather_pages(pool, block_tables):
-    """Assemble per-sequence logical K/V views from a physical page pool.
+def gather_pages(pool, layer, block_tables, kv_heads):
+    """Assemble per-sequence logical K/V views of one layer from the
+    stacked physical page pool.
 
-    pool: (n_pages, page, KV, D); block_tables: (B, n_pg) int32 physical
-    page ids.  Returns (B, n_pg*page, KV, D) — the contiguous cache view
-    the existing ``attn.qk``/``attn.pv`` substrate dispatches consume, so
-    a paged cache feeds the *same* GEMM plans as the dense one (the view
-    length equals the dense cache length by the engine's page|max_seq
-    contract, which is what keeps paged decoding bit-identical)."""
+    pool: (n_layers, n_pages, page, KV*D), a token's heads side by side on
+    one row; layer: int32 scalar (traced inside the layer scan);
+    block_tables: (B, n_pg) int32 physical page ids.  The pages are
+    gathered on (layer, page) straight from the stacked pool, so no
+    per-layer copy of the pool is made.  Returns (B, n_pg*page, KV, D) —
+    the contiguous cache view the existing ``attn.qk``/``attn.pv``
+    substrate dispatches consume, so a paged cache feeds the *same* GEMM
+    plans as the dense one (the view length equals the dense cache length
+    by the engine's page|max_seq contract, which is what keeps paged
+    decoding bit-identical)."""
     B, n_pg = block_tables.shape
-    page = pool.shape[1]
-    return pool[block_tables].reshape((B, n_pg * page) + pool.shape[2:])
-
-
-def scatter_pages(pool, block_tables, view):
-    """Inverse of :func:`gather_pages`: write logical views back into the
-    pool.  Rows may alias pages (shared prefixes, the scratch page); every
-    aliased write carries the unchanged gathered bytes, so the scatter's
-    pick-one-duplicate resolution is value-deterministic."""
-    B, n_pg = block_tables.shape
-    page = pool.shape[1]
-    blocks = view.reshape((B, n_pg, page) + pool.shape[2:])
-    return pool.at[block_tables].set(blocks)
+    page, row = pool.shape[2:]
+    return pool[layer, block_tables].reshape(
+        B, n_pg * page, kv_heads, row // kv_heads)
 
 
 def decode_attention(q, k_cache, v_cache, pos, *, window=0, backend="xla",

@@ -138,6 +138,16 @@ def jit_step(fn, cfg: ModelConfig):
     return jax.jit(step)
 
 
+def donate_cache(step):
+    """``step`` (a :func:`jit_step` or a function that calls one) jitted
+    again, under its own name, with its ``cache`` argument — the one after
+    the parameters — donated.  The paged steps write only the tick's new
+    K/V rows into the pool they are given, so with the pool donated they
+    update it in place, and the device holds it once, not twice.  The
+    caller must not read the pool it passed in again."""
+    return jax.jit(step, donate_argnums=(1,))
+
+
 @dataclass
 class Request:
     prompt: list
@@ -395,6 +405,7 @@ class ServingEngine:
         self._snapshots: List[dict] = []   # latest crash-recovery snapshot
         self.restored_requests: List[Request] = []  # set by restore()
 
+        self._launches = {}          # paged step -> donate_cache(step)
         self._prefill_launches = 0   # per-trace GEMM launches of one chunk
         # expert-kernel rows per dispatched token: every held expert of
         # every expert layer computes one row per token (moe_serve)
@@ -585,10 +596,21 @@ class ServingEngine:
         return np.asarray([s.write_pos for s in self.slots], np.int32)
 
     # -------------------------------------------------- guarded dispatch
-    def _guarded_dispatch(self, phase: str, dispatch, rows):
-        """Run one jit'd step under the fault guards: retry (at most
-        ``max_retries`` times) on a :class:`KernelFault` at the
-        trace/launch boundary, and on non-finite logits in the active
+    def _launch(self, step):
+        """``step`` as the engine launches it: a paged step with its pool
+        donated (:func:`donate_cache`, made once per step function, so a
+        step put in place after construction is donated too), a dense step
+        as it is."""
+        if not self.paged:
+            return step
+        if step not in self._launches:
+            self._launches[step] = donate_cache(step)
+        return self._launches[step]
+
+    def _guarded_dispatch(self, phase: str, dispatch, cache, rows):
+        """Run one jit'd step, ``dispatch(cache)``, under the fault guards:
+        retry (at most ``max_retries`` times) on a :class:`KernelFault` at
+        the trace/launch boundary, and on non-finite logits in the active
         ``rows`` (the ``engine.sample`` corruption point — also catches a
         *real* kernel producing NaN/Inf).  Returns ``(logits, new_cache,
         bad_rows, extra)``, ``extra`` being what the step returns beyond
@@ -601,16 +623,22 @@ class ServingEngine:
         finiteness readback, the first sync after a decode launch, in
         ``engine.<phase>.wait``.  Both add to ``<phase>_time_s``.
 
-        ``self.cache`` is only assigned by the caller after this returns:
-        the retry re-dispatches from the same pre-tick cache, so a
-        recovered tick is bit-identical to a clean one (and the PRNG key
-        is untouched — sampling happens after validation)."""
+        A paged step donates ``cache``: once launched, only the cache it
+        returned may be read.  A KernelFault fires while the step is
+        traced, before anything runs, so its retry still has ``cache``.  A
+        finiteness retry re-dispatches from the cache the failed launch
+        returned: a tick writes only its own rows and reads them from its
+        own chunk, never from the pool, so running it again gives the same
+        result, and a recovered tick is bit-identical to a clean one (the
+        PRNG key is untouched — sampling happens after validation).  The
+        caller assigns the returned cache; nothing reads the one it passed
+        in."""
         retries = max(0, self.sc.max_retries)
         total = f"{phase}_time_s"
         for attempt in range(retries + 1):
             try:
                 with self._span(f"engine.{phase}.launch", total):
-                    logits, new_cache, *extra = dispatch()
+                    logits, new_cache, *extra = dispatch(cache)
             except KernelFault:
                 if attempt < retries:
                     self.stats["kernel_fault_retries"] += 1
@@ -631,6 +659,7 @@ class ServingEngine:
             bad = tuple(r for r in rows if not bool(finite[r]))
             if bad and attempt < retries:
                 self.stats["sample_retries"] += 1
+                cache = new_cache
                 continue
             return logits, new_cache, bad, extra
         raise AssertionError("unreachable")
@@ -667,9 +696,9 @@ class ServingEngine:
         try:
             _, cache, _, extra = self._guarded_dispatch(
                 "prefill",
-                lambda: (None, *step(self.params, self._prefill_cache(),
-                                     *map(jnp.asarray, args))[1:]),
-                rows=())
+                lambda c: (None, *self._launch(step)(
+                    self.params, c, *map(jnp.asarray, args))[1:]),
+                self._prefill_cache(), rows=())
         except KernelFault as exc:
             for s in seqs:
                 self._finish(s.req, Outcome.FAILED,
@@ -840,9 +869,9 @@ class ServingEngine:
         try:
             logits, new_cache, bad, extra = self._guarded_dispatch(
                 "decode",
-                lambda: step(self.params, self.cache,
-                             *map(jnp.asarray, args)),
-                rows=[r for r, _ in rows])
+                lambda c: self._launch(step)(self.params, c,
+                                             *map(jnp.asarray, args)),
+                self.cache, rows=[r for r, _ in rows])
         except KernelFault as exc:
             for _, s in rows:
                 self._finish(s.req, Outcome.FAILED, f"KernelFault: {exc}")

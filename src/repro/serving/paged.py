@@ -4,7 +4,7 @@ The dense engine pre-allocates one ``(max_batch, max_seq)`` K/V region and
 binds every request to a fixed :class:`~repro.serving.engine.Slot`, so
 memory scales with the worst case and concurrency is hard-capped at
 ``max_batch``.  The paged engine instead owns a global **page pool** per
-layer — ``(n_pages, page_size, KV, hd)`` — and gives every admitted
+layer — ``(n_pages, page_size, KV * hd)`` — and gives every admitted
 sequence a **block table** mapping its logical cache positions to physical
 pages.  Admission tracks *free pages*, not free slots: a short request
 reserves ``ceil(target_len / page_size)`` pages, so many short sequences

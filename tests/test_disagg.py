@@ -128,6 +128,30 @@ def test_paged_disagg_stream_identical():
     assert eng.stats["kv_transfer_bytes"] > 0
 
 
+def test_paged_disagg_donates_both_pools():
+    """The paged disaggregated engine donates the prefill pods' pool to
+    its prefill step and the decode pods' pool to its decode step, and
+    reads neither after: a NaN retry on the decode pods re-dispatches from
+    the cache the failed launch returned, and the streams stay the
+    colocated engine's."""
+    from _paged_pool import assert_donated, record_donations
+    colo, _ = _run(ServingEngine, ServeConfig(**_KW))
+    eng = DisaggServingEngine(
+        _cfg(), _params(),
+        DisaggServeConfig(**_KW, kv_pages=40, page_size=16, prefill_pods=2,
+                          decode_pods=2, chaos=ChaosConfig(nan_logits_at=1)))
+    given = record_donations(eng)
+    rs = _reqs()
+    for r in rs:
+        eng.submit(r)
+    eng.run_to_completion()
+    assert {r.rid: (r.outcome, tuple(r.out_tokens)) for r in rs} == colo
+    assert eng.stats["sample_retries"] == 1
+    assert {step for step, _ in given} == {eng._prefill_paged,
+                                           eng._decode_paged}
+    assert_donated(given, eng.cache, eng.pcache)
+
+
 @needs4
 @pytest.mark.parametrize("backend", BACKENDS)
 def test_multidev_pp2_stream_identical(backend):

@@ -224,6 +224,18 @@ def test_tiny_qwen3_moe_paged_steps_match_a_plain_forward():
     assert routed.shape == (2,)
 
 
+def test_tiny_qwen3_moe_paged_steps_match_the_per_layer_pool_path():
+    """The tiny Qwen3-MoE's paged steps, with the pool donated and updated
+    in place, give the logits, ``routed`` and the whole pool, bit for bit,
+    of the steps that passed the pool through the layer scan
+    (tests/_paged_pool): a prefill and three decodes over a shared
+    prefix page, lazily reserved table entries and an idle row."""
+    from _paged_pool import check_steps_against_reference
+    cfg = _moe_cfg()
+    check_steps_against_reference(cfg, lm.init_params(cfg,
+                                                      jax.random.PRNGKey(7)))
+
+
 def test_tiny_qwen3_moe_serves_paged_as_token_prefill_does():
     """Through ``ServingEngine``: paged K/V with batched prefill, the dense
     batched path and token-by-token prefill give the same greedy streams;

@@ -269,9 +269,9 @@ def _decode_paged_page_writeback(p, x, cfg, cache, pos, bt, norm_scale):
     q = layers.apply_rope(q, positions, cfg.rope_theta)
     k_new = layers.apply_rope(k_new, positions, cfg.rope_theta)
     n_pg, page = bt.shape[1], cache["kp"].shape[1]
-    k_view = attn_lib.gather_pages(cache["kp"], bt)
-    v_view = attn_lib.gather_pages(cache["vp"], bt)
     L = n_pg * page
+    k_view = cache["kp"][bt].reshape((B, L) + cache["kp"].shape[2:])
+    v_view = cache["vp"][bt].reshape((B, L) + cache["vp"].shape[2:])
     slot = jnp.minimum(pos, L - 1)
     hit = (jnp.arange(L)[None, :] == slot[:, None])[:, :, None, None]
     k_view = jnp.where(hit, k_new.astype(cache["kp"].dtype), k_view)
@@ -297,10 +297,12 @@ def _decode_paged_page_writeback(p, x, cfg, cache, pos, bt, norm_scale):
 
 @pytest.mark.parametrize("seed", [0, 1])
 def test_decode_writes_one_row_per_live_sequence(model, seed):
-    """``attn_decode_paged`` returns the input pool with exactly the rows
-    ``(phys[b], slot[b] % page)`` of live sequences replaced by their new
-    K/V, and the same attention output, bit for bit, as the whole-page
-    write-back.  Rows cover ``slot % page`` at 0 and ``page - 1``, the
+    """``attn_decode_paged`` returns each row's new K/V, and the layer
+    scan's write of them (``lm._write_rows``) returns the input pool with
+    exactly the rows ``(phys[b], slot[b] % page)`` of live sequences
+    replaced; the attention output is, bit for bit, the whole-page
+    write-back's.  The pool is a stack of two layers, read and written at
+    the second.  Rows cover ``slot % page`` at 0 and ``page - 1``, the
     first and the last page, a clamped ``pos >= L``, a prefix page shared
     by two sequences, and two idle rows aliased to scratch page 0."""
     cfg, params = model
@@ -322,30 +324,52 @@ def test_decode_writes_one_row_per_live_sequence(model, seed):
     bt = jnp.asarray(bt)
     KV, hd = cfg.n_kv_heads, cfg.resolved_head_dim
     k1, k2, k3 = jax.random.split(jax.random.PRNGKey(seed), 3)
-    cache = {"kp": jax.random.normal(k1, (n_pages, page, KV, hd),
+    cache = {"kp": jax.random.normal(k1, (2, n_pages, page, KV * hd),
                                      jnp.bfloat16),
-             "vp": jax.random.normal(k2, (n_pages, page, KV, hd),
+             "vp": jax.random.normal(k2, (2, n_pages, page, KV * hd),
                                      jnp.bfloat16)}
     x = jax.random.normal(k3, (B, 1, cfg.d_model), lm._cdtype(cfg))
     blk = jax.tree.map(lambda a: a[0], params["blocks"][0])
     norm_scale = blk["ln1"]["scale"]
 
-    out, new = lm.attn_decode_paged(blk["attn"], x, cfg, cache, pos, bt,
-                                    norm_scale=norm_scale)
+    out, new_rows = lm.attn_decode_paged(blk["attn"], x, cfg, cache, 1, pos,
+                                         bt, norm_scale=norm_scale)
+    layer1 = {k: v[1].reshape(n_pages, page, KV, hd)
+              for k, v in cache.items()}
     ref_out, ref_kp, ref_vp, k_new, v_new = _decode_paged_page_writeback(
-        blk["attn"], x, cfg, cache, pos, bt, norm_scale)
+        blk["attn"], x, cfg, layer1, pos, bt, norm_scale)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref_out))
-
+    # the layer scan hands over both layers' rows: layer 0 rewrites its
+    # own bytes, layer 1 the new rows
     slot = np.minimum(np.asarray(pos), L - 1)
+    phys_all = np.asarray(bt)[np.arange(B), slot // page]
+    stacked = {k: jnp.stack([v[0, phys_all, slot % page], new_rows[k]])
+               for k, v in cache.items()}
+    (new,) = lm._write_rows((cache,), (stacked,), pos, None, bt)
+
     live = np.array([r[1] for r in rows])
-    phys = np.asarray(bt)[np.arange(B), slot // page][live]
-    off = (slot % page)[live]
+    phys, off = phys_all[live], (slot % page)[live]
     assert set(off) == {0, page - 1}
     for name, rows_new, ref in (("kp", k_new, ref_kp), ("vp", v_new, ref_vp)):
+        np.testing.assert_array_equal(
+            np.asarray(new_rows[name]),
+            np.asarray(rows_new.astype(jnp.bfloat16)).reshape(B, -1))
         want = np.array(cache[name])
-        want[phys, off] = np.asarray(rows_new.astype(jnp.bfloat16))[live]
+        want[1, phys, off] = np.asarray(
+            rows_new.astype(jnp.bfloat16)).reshape(B, -1)[live]
         got = np.asarray(new[name])
         # scratch page 0 takes the idle rows' writes; live rows never
         # attend it
-        np.testing.assert_array_equal(got[1:], want[1:])
-        np.testing.assert_array_equal(got[1:], np.asarray(ref)[1:])
+        np.testing.assert_array_equal(got[:, 1:], want[:, 1:])
+        np.testing.assert_array_equal(
+            got[1, 1:], np.asarray(ref).reshape(n_pages, page, -1)[1:])
+
+
+def test_paged_steps_match_the_per_layer_pool_path(model):
+    """A prefill and three decodes with the pool donated and updated in
+    place give the logits and the whole pool, bit for bit, of the steps
+    that passed the pool through the layer scan (tests/_paged_pool):
+    a shared prefix page, lazily reserved table entries and an idle row."""
+    from _paged_pool import check_steps_against_reference
+    cfg, params = model
+    check_steps_against_reference(cfg, params)

@@ -206,6 +206,31 @@ def test_persistent_gemm_fault_fails_typed_no_hang(model):
     assert eng.stats["outcome_failed"] == len(reqs)
 
 
+# ------------------------------------------- retries over a donated pool
+@pytest.mark.parametrize("chaos_cfg, counter", [
+    (ChaosConfig(nan_logits_at=0), "sample_retries"),
+    (ChaosConfig(nan_logits_at=3), "sample_retries"),
+    (ChaosConfig(gemm_fault_at=0), "kernel_fault_retries"),
+    (ChaosConfig(gemm_fault_at=2), "kernel_fault_retries"),
+], ids=["nan_first_decode", "nan_later_decode", "gemm_fault_first",
+        "gemm_fault_later"])
+def test_paged_retry_over_donated_pool_stream_identical(model, baseline,
+                                                        chaos_cfg, counter):
+    """The paged engine donates its pool to every step: a tick retried
+    after non-finite logits re-dispatches from the cache the failed launch
+    returned, a KernelFault retry from the pool the faulted trace never
+    consumed, and both give the clean streams.  Every pool handed to a
+    step was donated, and the engine ends holding a live one."""
+    from _paged_pool import assert_donated, record_donations
+    eng, reqs = _mk(model, kv_pages=40, page_size=8, chaos=chaos_cfg)
+    given = record_donations(eng)
+    eng.run_to_completion()
+    assert [r.out_tokens for r in reqs] == baseline
+    assert eng.stats[counter] >= 1
+    assert all(r.outcome == Outcome.OK.value for r in reqs)
+    assert_donated(given, eng.cache)
+
+
 # --------------------------------------------- page exhaustion + watchdog
 def test_page_exhaustion_chaos_terminates_all_typed(model):
     eng, reqs = _run(model, kv_pages=24, page_size=8,

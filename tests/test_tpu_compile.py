@@ -278,6 +278,84 @@ def test_qwen2_paged_decode_writes_back_rows_not_gathered_pages(one_chip):
     assert max(counts) < per_page, (counts, per_page)
 
 
+def _instructions(hlo_text):
+    """(opcode, dims or None for a tuple, root opcode of the computation a
+    fusion calls or None) of every instruction in ``hlo_text``."""
+    import re
+    roots, found = {}, []
+    comp = None
+    for ln in hlo_text.splitlines():
+        head = re.match(r"(?:ENTRY )?%([\w.\-]+) .*\{$", ln)
+        if head:
+            comp = head.group(1)
+            continue
+        m = re.match(r"\s*(ROOT )?%[\w.\-]+ = (\S+) ([\w\-]+)\(", ln)
+        if not m:
+            continue
+        if m.group(1):
+            roots[comp] = m.group(3)
+        arr = re.match(r"\w+\[([\d,]*)\]", m.group(2))
+        dims = ([int(d) for d in arr.group(1).split(",") if d]
+                if arr else None)
+        calls = re.search(r"calls=%([\w.\-]+)", ln)
+        found.append((m.group(3), dims, calls and calls.group(1)))
+    return [(op, dims, roots.get(c)) for op, dims, c in found]
+
+
+@pytest.mark.parametrize("phase", ["decode", "prefill"])
+@pytest.mark.parametrize("B, pages, max_seq", [(64, 20481, 256),
+                                               (128, 16385, 2048)],
+                         ids=["short-burst", "offline"])
+def test_qwen2_paged_steps_update_the_pool_in_place(one_chip, phase, B,
+                                                    pages, max_seq):
+    """The paged steps as the engine launches them (pool donated) at the
+    two qwen2-0.5b cells' shapes (chunk = B) make no pool-sized value:
+    no instruction's result has the pool's page count in its shape apart
+    from the parameters (the layer loop's own included), the output
+    (bitcasts of the scatters' results), and the only instructions made
+    of whole pages of the pool, at least one layer's pool in size, are the
+    one scatter per pool leaf (which may see the pool as rows) and the
+    fusions that run them.  The donated pool is aliased to the output."""
+    import math
+    from repro.serving.engine import donate_cache, jit_step
+
+    cfg, params, _ = _qwen2(one_chip)
+    page = 16
+    cache = _shapes(one_chip, jax.eval_shape(
+        lambda: lm.init_paged_cache(cfg, pages, page)))
+    bt = _i32(one_chip, B, max_seq // page)
+    if phase == "decode":
+        fn, args = lm.decode_step_paged, (_i32(one_chip, B),
+                                          _i32(one_chip, B), bt)
+    else:
+        fn, args = lm.prefill_step_paged, (_i32(one_chip, B, B),
+                                           _i32(one_chip, B),
+                                           _i32(one_chip, B), bt)
+    compiled = donate_cache(jit_step(fn, cfg)).lower(
+        params, cache, *args).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    leaves = jax.tree.leaves(cache)
+    layer_elems = math.prod(leaves[0].shape[1:])
+    views = {"parameter", "get-tuple-element", "bitcast"}
+    found = [(op, dims, root) for op, dims, root in _instructions(text)
+             if dims is not None]
+    paged = [op for op, dims, _ in found if pages in dims]
+    assert paged and set(paged) <= views, sorted(set(paged))
+    big = [(op, root) for op, dims, root in found
+           if math.prod(dims) >= layer_elems
+           and math.prod(dims) % (pages * page) == 0 and op not in views]
+    assert sorted(op for op, _ in big if op != "fusion") == \
+        ["scatter"] * len(leaves), big
+    assert all(root == "scatter" for op, root in big if op == "fusion"), big
+    ma = compiled.memory_analysis()
+    assert ma.alias_size_in_bytes >= sum(
+        math.prod(a.shape) * a.dtype.itemsize for a in leaves)
+    used = (ma.argument_size_in_bytes + ma.output_size_in_bytes
+            + ma.temp_size_in_bytes - ma.alias_size_in_bytes)
+    assert used < HBM_BYTES, used
+
+
 @pytest.mark.parametrize("phase", ["decode", "prefill"])
 def test_qwen3_moe_paged_steps_compile_with_one_kernel_per_site(one_chip,
                                                                 phase):
